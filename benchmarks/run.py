@@ -126,6 +126,8 @@ def main() -> None:
               f"known: {','.join(BENCHES)}", file=sys.stderr)
         sys.exit(2)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (adaptive_t, control, fig2_acc_vs_p, fig3_tstar,
                             fig4_heatmap, figs, kernel_micro, multihost,
                             roofline_report, round_loop, scenarios, serving,

@@ -63,9 +63,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro.dist import sharding as _sharding
 from repro.kernels import ops
 
 
@@ -277,6 +277,31 @@ def use_flat_lowering(mode: Optional[str] = None) -> bool:
 _use_flat_lowering = use_flat_lowering
 
 
+def _column_sharded_mix(w, flat, seg):
+    """``ops.gossip_mix_seg`` on the flat (m, P) buffer, under the bound
+    mesh when it spans several devices.
+
+    A Mosaic kernel cannot be partitioned by GSPMD, so on a multi-device
+    mesh the call runs inside ``jax.shard_map``: columns are independent
+    (y[:, j] needs only x[:, j] and W), so every device mixes its own
+    stripe of columns with the whole W. The reshard from client-sharded
+    rows to column stripes and back is the round's one exchange."""
+    mesh = _sharding.current_mesh()
+    if mesh is None or mesh.size == 1:
+        return ops.gossip_mix_seg(w, flat, seg)
+    axes = tuple(mesh.axis_names)
+    cols = flat.shape[1]
+    pad = (-cols) % mesh.size
+    if pad:
+        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)))
+    stripes = P(None, axes)
+    mixed = jax.shard_map(ops.gossip_mix_seg, mesh=mesh,
+                          in_specs=(P(), stripes, stripes),
+                          out_specs=stripes, check_vma=False)(w, flat, seg)
+    return mixed[:, :cols]
+
+
 def mix_tree_planned(W: jax.Array, lora, mask_a, mask_b, *,
                      plan: Optional[MixPlan] = None,
                      flat_lowering: Optional[str] = None):
@@ -284,10 +309,11 @@ def mix_tree_planned(W: jax.Array, lora, mask_a, mask_b, *,
 
     Masks are folded into per-segment effective mixing matrices
     W_eff = mask·W + (1−mask)·I — the blend never touches the (m, P)
-    payload as a separate pass. Under a mesh (or on TPU) the whole tree is
-    mixed by ONE gossip_mix_seg kernel call / ONE collective on the
-    plan's padded flat layout; otherwise each slot is a single dot with
-    its segment's W_eff. Numerically equal to mix_tree for all masks and
+    payload as a separate pass. With the flat lowering the whole tree is
+    mixed by ONE gossip_mix_seg kernel call on the plan's padded flat
+    layout (column-sharded across a multi-device mesh — see
+    `_column_sharded_mix`); otherwise each slot is a single dot with its
+    segment's W_eff. Numerically equal to mix_tree for all masks and
     bit-for-bit at equal masks (W_eff reduces to W exactly).
 
     ``flat_lowering`` pins the buffer lowering for this call ("flat" /
@@ -305,7 +331,7 @@ def mix_tree_planned(W: jax.Array, lora, mask_a, mask_b, *,
                                    parts[0].dtype))
         flat = jnp.concatenate(parts, axis=1)
         seg = plan.segment_mask(mask_a, mask_b).astype(flat.dtype)
-        mixed = ops.gossip_mix_seg(W.astype(flat.dtype), flat, seg)
+        mixed = _column_sharded_mix(W.astype(flat.dtype), flat, seg)
         out = []
         for slot, leaf in zip(plan.slots, leaves):
             chunk = mixed[:, slot.offset:slot.offset + slot.cols]
@@ -510,7 +536,6 @@ def mix_tree_sparse(W: jax.Array, lora, mask_a, mask_b, *, comm_plan,
     degenerate and distributed paths quantize identically (per-row), so
     grid parity stays bitwise.
     """
-    from repro.dist import sharding as _sharding
     plan = plan if plan is not None else get_mix_plan(lora)
     leaves = jax.tree_util.tree_leaves(lora)
     m = plan.m
@@ -653,6 +678,6 @@ def _exchange_and_mix(W, flat, prev_flat, mask_a, mask_b, plan: MixPlan,
         args.append(ef)
     out_specs = (P(axis, None), P(axis, None)) if quantized \
         else P(axis, None)
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     return fn(*args)

@@ -68,10 +68,8 @@ def initialize(coordinator: Optional[str] = None,
         process_id = int(os.environ[ENV_PROCESS_ID])
     if coordinator is None or num_processes is None or num_processes <= 1:
         return False
-    try:  # CPU multi-process collectives route through gloo
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — config name varies across jax versions
-        pass
+    # CPU multi-process collectives route through gloo
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

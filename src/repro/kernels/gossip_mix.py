@@ -32,9 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _resolve_bp(P: int, bp: int) -> int:
@@ -96,7 +94,7 @@ def gossip_mix(w_eff: jax.Array, x: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, bp), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, P), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
@@ -154,7 +152,7 @@ def gossip_mix_quant(w_off: jax.Array, q: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((r, bp), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((r, P), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(w_off, q, scale, x, w_diag, seg)

@@ -24,9 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
@@ -80,7 +78,7 @@ def lora_matmul(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, a, b)
@@ -96,7 +94,7 @@ def _slot_kernel(slot_ref, x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         xa_ref[...] = jnp.zeros_like(xa_ref)
 
-    xb = x_ref[...]                   # (1, bk) — one decode slot's row
+    xb = x_ref[0]                     # (1, bk) — one decode slot's row
     acc_ref[...] += jnp.dot(xb, w_ref[...],
                             preferred_element_type=jnp.float32)
     xa_ref[...] += jnp.dot(xb, a_ref[0],
@@ -106,7 +104,7 @@ def _slot_kernel(slot_ref, x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref,
     def _finish():
         corr = jnp.dot(xa_ref[...], b_ref[0].astype(jnp.float32),
                        preferred_element_type=jnp.float32)
-        o_ref[...] = (acc_ref[...] + scale * corr).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] + scale * corr).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bn", "bk",
@@ -123,7 +121,9 @@ def slot_lora_matmul(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,
     adapter row s_i for grid row i, so the gather costs one block choice, not
     a materialized (B, K, r) gather in HBM. Row blocks are bm=1 (decode B is
     the slot count, single tokens); the dense product still tiles (bk, bn)
-    on the MXU.
+    on the MXU. x and the output are viewed as (B, 1, K) / (B, 1, N) so a
+    row block ends in (1, bk) / (1, bn) over a size-1 axis: Mosaic needs
+    the last two block dims divisible by (8, 128) or equal to the array's.
     """
     B, K = x.shape
     N = w.shape[1]
@@ -139,22 +139,23 @@ def slot_lora_matmul(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, slots: (i, k)),
+            pl.BlockSpec((1, 1, bk), lambda i, j, k, slots: (i, 0, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k, slots: (k, j)),
             pl.BlockSpec((1, bk, r), lambda i, j, k, slots: (slots[i], k, 0)),
             pl.BlockSpec((1, r, bn), lambda i, j, k, slots: (slots[i], 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, slots: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, bn), lambda i, j, k, slots: (i, 0, j)),
         scratch_shapes=[
             pltpu.VMEM((1, bn), jnp.float32),
             pltpu.VMEM((1, r), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_slot_kernel, scale=scale, nk=nk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
-        compiler_params=compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, 1, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(slots.astype(jnp.int32), x, w, a, b)
+    )(slots.astype(jnp.int32), x.reshape(B, 1, K), w, a, b)
+    return y.reshape(B, N)

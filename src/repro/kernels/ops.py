@@ -57,15 +57,15 @@ def slot_lora_matmul(x, w, a, b, slots, scale: float = 1.0):
 
 def paged_attn_decode(q, k_pages, v_pages, table, lengths):
     """Single-token decode attention over a paged KV cache (the serving
-    core's gather). q: (B, 1, H, hd); k_pages/v_pages: (n_pages,
-    page_size, KV, hd); table: (B, P) int32; lengths: (B,). The ref
+    core's gather). q: (B, 1, H, hd); k_pages/v_pages: (n_pages, KV,
+    page_size, hd); table: (B, P) int32; lengths: (B,). The ref
     oracle is bitwise-identical to the contiguous decode path; the
     Pallas kernel is the flash-decode accumulation (tolerance)."""
     m = _mode()
     if m == "ref":
         return ref.paged_attn_decode_ref(q, k_pages, v_pages, table, lengths)
     B, _, H, hd = q.shape
-    n_kv = k_pages.shape[2]
+    n_kv = k_pages.shape[1]
     qg = q.reshape(B, n_kv, H // n_kv, hd)
     out = _paged_attn(qg, k_pages, v_pages, table, lengths,
                       interpret=(m == "interpret"))

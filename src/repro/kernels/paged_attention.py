@@ -2,9 +2,12 @@
 
 One decode token per batch row attends over a KV cache stored as
 fixed-size physical pages shared by all rows: ``k_pages``/``v_pages``
-are ``(n_pages, page_size, KV, hd)`` and each row's block table maps its
-logical page index to a physical page. The gather happens INSIDE the
-kernel via scalar-prefetched block index maps (the same
+are ``(n_pages, KV, page_size, hd)`` and each row's block table maps its
+logical page index to a physical page. Heads lead the page so that one
+grid step's K/V block ``(1, 1, page_size, hd)`` ends in the page's own
+two trailing dims, which Mosaic's (8, 128) tiling rule always admits.
+The gather happens INSIDE the kernel via scalar-prefetched block index
+maps (the same
 `PrefetchScalarGridSpec` pattern as `slot_lora_matmul`): grid step
 ``(b, kv, p)`` DMAs physical page ``table[b, p]``, so page occupancy is
 data — growing, shrinking, or remapping a row's pages never changes a
@@ -32,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, page_size: int, n_pseq: int,
@@ -48,8 +49,8 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)               # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page_size, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)               # (page_size, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     k_pos = p * page_size + jax.lax.broadcasted_iota(
@@ -75,11 +76,11 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       table: jax.Array, lengths: jax.Array, *,
                       interpret: bool = False) -> jax.Array:
     """q: (B, KV, G, hd) grouped decode queries; k_pages/v_pages:
-    (n_pages, page_size, KV, hd); table: (B, P) int32; lengths: (B,)
+    (n_pages, KV, page_size, hd); table: (B, P) int32; lengths: (B,)
     valid context per row (>= 1 for rows whose output is read).
     Returns (B, KV, G, hd)."""
     B, KV, G, hd = q.shape
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     P = table.shape[1]
     scale = 1.0 / math.sqrt(hd)
 
@@ -90,12 +91,12 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, kv, p, tbl, lens: (b, kv, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, kv, p, tbl, lens: (tbl[b * P + p], 0,
-                                                      kv, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, kv, p, tbl, lens: (tbl[b * P + p], 0,
-                                                      kv, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, kv, p, tbl, lens: (tbl[b * P + p], kv,
+                                                      0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, kv, p, tbl, lens: (tbl[b * P + p], kv,
+                                                      0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, kv, p, tbl, lens: (b, kv, 0, 0)),
@@ -110,7 +111,7 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),

@@ -65,7 +65,7 @@ def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
                           lengths: jax.Array) -> jax.Array:
     """Single-token decode attention over a paged KV cache.
 
-    q: (B, 1, H, hd); k_pages/v_pages: (n_pages, page_size, KV, hd);
+    q: (B, 1, H, hd); k_pages/v_pages: (n_pages, KV, page_size, hd);
     table: (B, P) int32 logical->physical page map; lengths: (B,) valid
     context per row. Returns (B, 1, H, hd).
 
@@ -76,11 +76,11 @@ def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
     decode path bit-for-bit — the serving-core correctness contract
     asserted by tests/test_paging.py."""
     B, Sq, H, hd = q.shape
-    ps, n_kv = k_pages.shape[1], k_pages.shape[2]
+    n_kv, ps = k_pages.shape[1], k_pages.shape[2]
     P = table.shape[1]
     L = P * ps
-    k = k_pages[table].reshape(B, L, n_kv, hd)
-    v = v_pages[table].reshape(B, L, n_kv, hd)
+    k = k_pages[table].swapaxes(2, 3).reshape(B, L, n_kv, hd)
+    v = v_pages[table].swapaxes(2, 3).reshape(B, L, n_kv, hd)
     mask = (jnp.arange(L)[None, :] < lengths[:, None])[:, None, None, None, :]
     G = H // n_kv
     qg = q.reshape(B, Sq, n_kv, G, hd)

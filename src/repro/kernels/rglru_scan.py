@@ -13,9 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, u_ref, o_ref, h_ref, *, bt: int):
@@ -54,7 +52,7 @@ def rglru_scan(a: jax.Array, u: jax.Array, *, bt: int = 256,
         out_specs=pl.BlockSpec((1, bt, W), lambda b, t: (b, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, W), u.dtype),
         scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, u)

@@ -10,8 +10,11 @@ this with its own ``REPRO_PROCESS_ID``.
 
 *Parent* (``--simulate N``): spawn N local worker processes on the
 portable CPU backend (gloo collectives), forward the remaining CLI args to
-each, stream rank 0's output, and exit non-zero if any worker fails. This
-is how CI exercises the whole multi-process path headless:
+each, stream rank 0's output, and exit non-zero if any worker fails. It
+is the CPU rehearsal of a process grid, and how CI exercises the whole
+multi-process path headless. A chip belongs to one process, so on a chip
+host the multi-chip path is a single worker (no ``--simulate``) whose
+`ClusterSession` spans every local chip:
 
   PYTHONPATH=src python -m repro.launch.cluster --simulate 2 \\
       --preset classifier --rounds 6 --clients 4 --json out.json
@@ -113,6 +116,8 @@ def worker_main(args) -> int:
 
     import jax
     from repro.api import ClusterSession, ConsoleLogger, DFLConfig
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.config:
         with open(args.config) as f:
@@ -242,8 +247,11 @@ def _parser() -> argparse.ArgumentParser:
         description="multi-process DFL (worker, or --simulate N parent)",
         allow_abbrev=False)
     ap.add_argument("--simulate", type=int, default=0, metavar="N",
-                    help="spawn N local worker processes and wait (parent "
-                         "mode); 0 = run as a worker")
+                    help="spawn N local worker processes on the CPU "
+                         "backend and wait (parent mode): the CPU "
+                         "rehearsal of a process grid. On a chip host the "
+                         "multi-chip path is ONE worker process over all "
+                         "local chips. 0 = run as a worker")
     # grid (worker mode; REPRO_* env is the usual source)
     ap.add_argument("--coordinator", default=None)
     ap.add_argument("--num-processes", type=int, default=None)

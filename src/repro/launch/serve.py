@@ -22,6 +22,7 @@ import numpy as np
 from repro.api.serving import AdapterPool, ServingSession
 from repro.checkpoint import load_pytree
 from repro.core.lora import client_mean, merge_lora
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 
 
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.key(args.seed)
     pool = None

@@ -16,6 +16,7 @@ import json
 import os
 
 from repro.api import ConsoleLogger, DFLConfig, HistoryRecorder, Session
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--log", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     config = DFLConfig(
         model=args.arch, task="lm", reduced=not args.full,

@@ -246,11 +246,13 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
     per-slot position counters. The logical->physical block table lives at
     the cache top level (`transformer.init_cache(paging=...)`) because one
     table serves every paged layer. Physical page 0 is the null page —
-    free slots' table rows point at it and no active slot ever reads it."""
+    free slots' table rows point at it and no active slot ever reads it.
+    Pages are (KV, page_size, hd): heads lead so the paged kernel's
+    per-head block is a whole (page_size, hd) tile."""
     kv, hd = cfg.n_kv_heads, cfg.hd
     return {
-        "kp": zeros(n_pages, page_size, kv, hd, dtype=dtype),
-        "vp": zeros(n_pages, page_size, kv, hd, dtype=dtype),
+        "kp": zeros(n_pages, kv, page_size, hd, dtype=dtype),
+        "vp": zeros(n_pages, kv, page_size, hd, dtype=dtype),
         "t": jnp.zeros((batch,), dtype=jnp.int32),
     }
 
@@ -261,8 +263,8 @@ def paged_cache_spec(cfg: ModelConfig, batch: int, n_pages: int,
     kv, hd = cfg.n_kv_heads, cfg.hd
     f = jax.ShapeDtypeStruct
     return {
-        "kp": f((n_pages, page_size, kv, hd), dtype),
-        "vp": f((n_pages, page_size, kv, hd), dtype),
+        "kp": f((n_pages, kv, page_size, hd), dtype),
+        "vp": f((n_pages, kv, page_size, hd), dtype),
         "t": f((batch,), jnp.int32),
     }
 
@@ -335,14 +337,16 @@ def _paged_decode_core(cfg: ModelConfig, q, k_new, v_new, cache: dict,
     B = q.shape[0]
     t = cache["t"]
     table = pages["table"]                             # (B, P)
-    ps = cache["kp"].shape[1]
+    ps = cache["kp"].shape[2]
     P = table.shape[1]
     L = P * ps
     rows = jnp.arange(B)
     phys = table[rows, jnp.clip(t // ps, 0, P - 1)]    # (B,)
     off = t % ps
-    kp = cache["kp"].at[phys, off].set(k_new[:, 0].astype(cache["kp"].dtype))
-    vp = cache["vp"].at[phys, off].set(v_new[:, 0].astype(cache["vp"].dtype))
+    kp = cache["kp"].at[phys, :, off].set(
+        k_new[:, 0].astype(cache["kp"].dtype))
+    vp = cache["vp"].at[phys, :, off].set(
+        v_new[:, 0].astype(cache["vp"].dtype))
     lengths = jnp.minimum(t + 1, L)
     out = ops.paged_attn_decode(q, kp, vp, table, lengths)
     out = out.reshape(B, 1, cfg.n_heads * cfg.hd)
@@ -392,7 +396,7 @@ def attn_chunk_paged(params: dict, cfg: ModelConfig, x, cache: dict,
     pos = start + jnp.arange(C)                        # (C,) absolute
     q, k_new, v_new = _chunk_qkv(params, cfg, x, pos, lora, scale)
 
-    ps = cache["kp"].shape[1]
+    ps = cache["kp"].shape[2]
     P = table_row.shape[0]
     L = P * ps
     pos_c = jnp.clip(pos, 0, L - 1)                    # pads stay in range
@@ -400,14 +404,16 @@ def attn_chunk_paged(params: dict, cfg: ModelConfig, x, cache: dict,
     off = pos_c % ps
     valid_w = (pos < limit)[:, None, None]
     kw = jnp.where(valid_w, k_new[0].astype(cache["kp"].dtype),
-                   cache["kp"][phys, off])
+                   cache["kp"][phys, :, off])
     vw = jnp.where(valid_w, v_new[0].astype(cache["vp"].dtype),
-                   cache["vp"][phys, off])
-    kp = cache["kp"].at[phys, off].set(kw)
-    vp = cache["vp"].at[phys, off].set(vw)
+                   cache["vp"][phys, :, off])
+    kp = cache["kp"].at[phys, :, off].set(kw)
+    vp = cache["vp"].at[phys, :, off].set(vw)
 
-    k_all = kp[table_row].reshape(1, L, cfg.n_kv_heads, cfg.hd)
-    v_all = vp[table_row].reshape(1, L, cfg.n_kv_heads, cfg.hd)
+    k_all = kp[table_row].swapaxes(1, 2).reshape(1, L, cfg.n_kv_heads,
+                                                 cfg.hd)
+    v_all = vp[table_row].swapaxes(1, 2).reshape(1, L, cfg.n_kv_heads,
+                                                 cfg.hd)
     k_pos = jnp.arange(L)
     mask = (k_pos[None, :] <= pos[:, None]) & (k_pos[None, :] < limit)
     out = _attend(q, k_all, v_all, mask[None, None, None], cfg.n_kv_heads)

@@ -3,7 +3,7 @@
 The contiguous serving cache sizes every slot for ``max_len`` tokens up
 front, so ``n_slots x max_len`` is a compile-time memory wall. Paging
 splits the global-attention KV buffers into fixed-size physical pages
-(``(n_pages, page_size, n_kv, head_dim)``) shared by all slots; each slot
+(``(n_pages, n_kv, page_size, head_dim)``) shared by all slots; each slot
 holds a *block table* row mapping its logical page index to a physical
 page. The compiled decode step receives the table as data — occupancy
 changes never retrace.

@@ -1,0 +1,189 @@
+"""Compile the main path's kernels and the sharded round for a TPU v5e.
+
+The chip is described, not attached: `jax.experimental.topologies` gives
+the devices of a v5e:2x2 host and XLA's TPU compiler runs against them,
+so what the chip's compiler refuses (unaligned blocks, too much VMEM, a
+kernel GSPMD cannot partition) fails here, at no chip time. Nothing runs.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file. Code that dispatches on ``jax.default_backend()`` still
+sees the CPU here, so each test steers `kernels.ops` to the Pallas path
+itself.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.api.rounds import build_round
+from repro.configs import get_config
+from repro.core import mixing
+from repro.core.lora import build_lora_tree
+from repro.dist import sharding
+from repro.kernels import ops
+from repro.kernels.gossip_mix import gossip_mix, gossip_mix_quant
+from repro.kernels.lora_matmul import slot_lora_matmul
+from repro.kernels.paged_attention import paged_attn_decode
+from repro.models import transformer as tf
+from repro.optim.adamw import AdamW
+
+M_CLIENTS = 8
+N_SLOTS = 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip: keep it out of the cache
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU here"
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def pallas(monkeypatch):
+    """Send `kernels.ops` dispatch to the compiled Pallas kernels."""
+    monkeypatch.setattr(ops, "_mode", lambda: "pallas")
+
+
+def _spec(shape, dtype, sharding_):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding_)
+
+
+def _compile(fn, *specs) -> str:
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _gemma_lora_cols() -> int:
+    """Columns per client of gemma3-1b's flat LoRA buffer (padded)."""
+    cfg = get_config("gemma3-1b")
+    lora = jax.eval_shape(
+        lambda k: build_lora_tree(k, tf.init_params(k, cfg), cfg,
+                                  n_clients=M_CLIENTS),
+        jax.random.key(0))
+    return mixing.build_mix_plan(lora).padded
+
+
+def test_gossip_mix_seg_compiles_at_gemma3_1b_width(topo, one_chip):
+    P_ = _gemma_lora_cols()
+    hlo = _compile(lambda w, x, s: gossip_mix(w, x, s),
+                   _spec((M_CLIENTS, M_CLIENTS), jnp.float32, one_chip),
+                   _spec((M_CLIENTS, P_), jnp.float32, one_chip),
+                   _spec((1, P_), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("quant", [jnp.int8, jnp.float8_e4m3fn])
+def test_gossip_mix_quant_compiles_at_gemma3_1b_width(topo, one_chip, quant):
+    P_ = _gemma_lora_cols()
+    r = M_CLIENTS // 4                    # one chip's rows of a 4-chip grid
+    hlo = _compile(gossip_mix_quant,
+                   _spec((r, M_CLIENTS), jnp.float32, one_chip),
+                   _spec((M_CLIENTS, P_), quant, one_chip),
+                   _spec((M_CLIENTS, 1), jnp.float32, one_chip),
+                   _spec((r, P_), jnp.float32, one_chip),
+                   _spec((r, 1), jnp.float32, one_chip),
+                   _spec((1, P_), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("target", ["wq", "wv"])
+def test_slot_lora_matmul_compiles_at_gemma3_1b_width(topo, one_chip,
+                                                      target):
+    cfg = get_config("gemma3-1b")
+    K = cfg.d_model
+    N = cfg.n_heads * cfg.hd if target == "wq" else cfg.n_kv_heads * cfg.hd
+    r, n_ad = cfg.lora_rank, M_CLIENTS + 2     # + base and consensus rows
+    hlo = _compile(lambda x, w, a, b, s: slot_lora_matmul(x, w, a, b, s,
+                                                          2.0),
+                   _spec((N_SLOTS, K), jnp.float32, one_chip),
+                   _spec((K, N), jnp.float32, one_chip),
+                   _spec((n_ad, K, r), jnp.float32, one_chip),
+                   _spec((n_ad, r, N), jnp.float32, one_chip),
+                   _spec((N_SLOTS,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n_kv,n_heads,hd", [(1, 4, 256),    # gemma3-1b
+                                             (4, 28, 128)])  # qwen2-7b
+def test_paged_attn_decode_compiles(topo, one_chip, n_kv, n_heads, hd):
+    page_size, pages_per_seq = 16, 16
+    n_pages = 1 + N_SLOTS * pages_per_seq
+    G = n_heads // n_kv
+    page = (n_pages, n_kv, page_size, hd)
+    hlo = _compile(paged_attn_decode,
+                   _spec((N_SLOTS, n_kv, G, hd), jnp.float32, one_chip),
+                   _spec(page, jnp.float32, one_chip),
+                   _spec(page, jnp.float32, one_chip),
+                   _spec((N_SLOTS, pages_per_seq), jnp.int32, one_chip),
+                   _spec((N_SLOTS,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
+    """The DFL round with the flat gossip kernel, client axis sharded over
+    a 4-chip mesh: the kernel must sit inside a shard_map (a Mosaic call
+    cannot be partitioned automatically)."""
+    cfg = get_config("gemma3-1b").reduced()
+    ls, b, S = 1, 2, 16
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rep = NamedSharding(mesh, P())
+
+    def client_sharded(x, axis):
+        spec = [None] * x.ndim
+        spec[axis] = "data"
+        return _spec(x.shape, x.dtype, NamedSharding(mesh, P(*spec)))
+
+    def loss_fn(bp, lo, micro):
+        out, per = tf.lm_loss(bp, cfg, micro["tokens"], micro["targets"],
+                              lora=lo, per_client=True)
+        return out[0], per
+
+    opt = AdamW(lr=1e-3)
+    key = jax.random.key(0)
+    base = jax.eval_shape(lambda k: tf.init_params(k, cfg), key)
+    lora = jax.eval_shape(
+        lambda k: build_lora_tree(k, tf.init_params(k, cfg), cfg,
+                                  n_clients=M_CLIENTS), key)
+    opt_state = jax.eval_shape(opt.init, lora)
+    tok = jax.ShapeDtypeStruct((ls, M_CLIENTS, b, S), jnp.int32)
+    specs = (
+        jax.tree.map(lambda x: _spec(x.shape, x.dtype, rep), base),
+        jax.tree.map(lambda x: client_sharded(x, x.ndim - 3), lora),
+        opt_state._replace(
+            step=_spec((), jnp.int32, rep),
+            mu=jax.tree.map(lambda x: client_sharded(x, x.ndim - 3),
+                            opt_state.mu),
+            nu=jax.tree.map(lambda x: client_sharded(x, x.ndim - 3),
+                            opt_state.nu)),
+        {"tokens": client_sharded(tok, 1), "targets": client_sharded(tok, 1)},
+        _spec((M_CLIENTS, M_CLIENTS), jnp.float32, rep),
+        _spec((4,), jnp.float32, rep),
+    )
+    round_fn = build_round(loss_fn, opt, local_steps=ls,
+                           mix_flat_lowering="flat")
+    sharding.set_mesh(mesh)
+    try:
+        hlo = _compile(round_fn, *specs)
+    finally:
+        sharding.clear_mesh()
+    assert "tpu_custom_call" in hlo

@@ -115,8 +115,8 @@ def test_paged_attn_kernel_vs_ref(B, KV, G, hd, ps, P, dtype, key):
     n_pages = 1 + B * P
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd), dtype)
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, hd), dtype)
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, hd), dtype)
+    kp = jax.random.normal(ks[1], (n_pages, KV, ps, hd), dtype)
+    vp = jax.random.normal(ks[2], (n_pages, KV, ps, hd), dtype)
     rng = np.random.default_rng(B * ps)
     perm = rng.permutation(np.arange(1, n_pages))      # non-trivial mapping
     table = jnp.asarray(perm.reshape(B, P), jnp.int32)
@@ -145,12 +145,14 @@ def test_paged_ref_matches_contiguous_attention(key):
     rng = np.random.default_rng(0)
     perm = rng.permutation(np.arange(1, 1 + B * P))
     table = perm.reshape(B, P)
-    kp = jnp.zeros((1 + B * P, ps, KV, hd))
-    vp = jnp.zeros((1 + B * P, ps, KV, hd))
+    kp = jnp.zeros((1 + B * P, KV, ps, hd))
+    vp = jnp.zeros((1 + B * P, KV, ps, hd))
     for b in range(B):
         for p in range(P):
-            kp = kp.at[table[b, p]].set(kc[b, p * ps:(p + 1) * ps])
-            vp = vp.at[table[b, p]].set(vc[b, p * ps:(p + 1) * ps])
+            kp = kp.at[table[b, p]].set(
+                kc[b, p * ps:(p + 1) * ps].swapaxes(0, 1))
+            vp = vp.at[table[b, p]].set(
+                vc[b, p * ps:(p + 1) * ps].swapaxes(0, 1))
     lengths = jnp.asarray([L, L - ps + 1], jnp.int32)
 
     y = ref.paged_attn_decode_ref(q, kp, vp, jnp.asarray(table, jnp.int32),
