@@ -570,27 +570,48 @@ class Session:
 
     def _one_round(self, *, is_last: bool, notify: bool,
                    want_event: bool = False) -> Optional[RoundEvent]:
-        t = self.t
-        join_fn = getattr(self.topo_schedule, "join_events", None)
-        if join_fn is not None:
-            joiners = tuple(join_fn(t))
-            if joiners:
-                self._warm_start_clients(joiners)
-        batch = self._to_device(next(self._batches))
-        W_np = self.topo_schedule.next_w(t)
-        masks = self.schedule.next_masks(
-            t, {"W": W_np, "round": t, "session": self})
-        W_dev = self._device_scalar_inputs(np.asarray(W_np, np.float32))
-        masks_dev = self._device_scalar_inputs(masks.as_array())
-        if self.ef is not None:
-            # quantized round: the error-feedback buffer threads through
-            self.lora, self.opt_state, metrics, self.ef = self.round_fn(
-                self.base, self.lora, self.opt_state, batch, W_dev,
-                masks_dev, self.ef)
-        else:
-            self.lora, self.opt_state, metrics = self.round_fn(
-                self.base, self.lora, self.opt_state, batch, W_dev,
-                masks_dev)
+        # host spans on the profiler's clock; recorded only while a
+        # profiler trace is on (jax.profiler.start_trace)
+        t, cfg = self.t, self.config
+        with jax.profiler.StepTraceAnnotation(
+                "repro.round", step_num=t,
+                tokens=(cfg.n_clients * cfg.local_steps * cfg.batch_size
+                        * cfg.seq_len)):
+            join_fn = getattr(self.topo_schedule, "join_events", None)
+            if join_fn is not None:
+                joiners = tuple(join_fn(t))
+                if joiners:
+                    with jax.profiler.TraceAnnotation("repro.round.joins"):
+                        self._warm_start_clients(joiners)
+            with jax.profiler.TraceAnnotation("repro.round.batch"):
+                batch = self._to_device(next(self._batches))
+            with jax.profiler.TraceAnnotation("repro.round.topology"):
+                W_np = self.topo_schedule.next_w(t)
+                masks = self.schedule.next_masks(
+                    t, {"W": W_np, "round": t, "session": self})
+            with jax.profiler.TraceAnnotation("repro.round.put"):
+                W_dev = self._device_scalar_inputs(
+                    np.asarray(W_np, np.float32))
+                masks_dev = self._device_scalar_inputs(masks.as_array())
+            with jax.profiler.TraceAnnotation("repro.round.dispatch"):
+                if self.ef is not None:
+                    # quantized round: the error-feedback buffer threads
+                    # through
+                    self.lora, self.opt_state, metrics, self.ef = \
+                        self.round_fn(self.base, self.lora, self.opt_state,
+                                      batch, W_dev, masks_dev, self.ef)
+                else:
+                    self.lora, self.opt_state, metrics = self.round_fn(
+                        self.base, self.lora, self.opt_state, batch, W_dev,
+                        masks_dev)
+            with jax.profiler.TraceAnnotation("repro.round.observe"):
+                return self._observe(t, W_np, masks, metrics,
+                                     is_last=is_last, notify=notify,
+                                     want_event=want_event)
+
+    def _observe(self, t: int, W_np, masks: RoundMasks, metrics, *,
+                 is_last: bool, notify: bool,
+                 want_event: bool) -> Optional[RoundEvent]:
         self.last_metrics = metrics
         # one observation payload per round, shared by the control loop
         # and every callback (construction is lazy — no device sync here)

@@ -129,14 +129,17 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                 # rides along as aux so the loss can be re-reduced in a
                 # grid-invariant order on host (scalar-only loss_fns get
                 # a length-1 vector — reporting then equals the scalar)
-                out = loss_fn(base_params, l, micro)
+                with jax.named_scope("loss"):
+                    out = loss_fn(base_params, l, micro)
                 if isinstance(out, tuple):
                     return out
                 return out, jnp.reshape(out, (1,))
 
             (loss, per), grads = jax.value_and_grad(
                 objective, has_aux=True)(lo)
-            lo, opt = optimizer.update(grads, opt, lo, update_mask=mask_fn)
+            with jax.named_scope("opt"):
+                lo, opt = optimizer.update(grads, opt, lo,
+                                           update_mask=mask_fn)
             lo = shard_lora_tree(lo)
             return (lo, opt), (loss, per)
 
@@ -155,18 +158,21 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
             base_params, lora, opt_state, batch, masks)
 
         # Joint mixing (Algorithm 1 lines 7–9): masks select per method.
-        if mix_comm == "dense":
-            if mix_gather:
-                lora_new = gather_clients(lora_new)
-            lora_new = mix(W, lora_new, masks[2], masks[3])
-        else:
-            # overlap feeds the ROUND-INPUT state to the off-diagonal
-            # terms: its exchange is independent of the local-steps scan
-            lora_new = mixing.mix_tree_sparse(
-                W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
-                lora_prev=(lora if mix_comm == "sparse_overlap" else None),
-                flat_lowering=mix_flat_lowering)
-        lora_new = shard_lora_tree(lora_new)
+        with jax.named_scope("mix"):
+            if mix_comm == "dense":
+                if mix_gather:
+                    lora_new = gather_clients(lora_new)
+                lora_new = mix(W, lora_new, masks[2], masks[3])
+            else:
+                # overlap feeds the ROUND-INPUT state to the off-diagonal
+                # terms: its exchange is independent of the local-steps
+                # scan
+                lora_new = mixing.mix_tree_sparse(
+                    W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
+                    lora_prev=(lora if mix_comm == "sparse_overlap"
+                               else None),
+                    flat_lowering=mix_flat_lowering)
+            lora_new = shard_lora_tree(lora_new)
         metrics = _metrics(losses, per_client)
         return lora_new, opt_new, metrics
 
@@ -175,11 +181,12 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
         (lora_new, opt_new), (losses, per_client) = _local_phase(
             base_params, lora, opt_state, batch, masks)
 
-        lora_new, ef_new = mixing.mix_tree_sparse(
-            W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
-            lora_prev=(lora if mix_comm == "sparse_overlap" else None),
-            flat_lowering=mix_flat_lowering, quant=mix_quant, ef=ef)
-        lora_new = shard_lora_tree(lora_new)
+        with jax.named_scope("mix"):
+            lora_new, ef_new = mixing.mix_tree_sparse(
+                W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
+                lora_prev=(lora if mix_comm == "sparse_overlap" else None),
+                flat_lowering=mix_flat_lowering, quant=mix_quant, ef=ef)
+            lora_new = shard_lora_tree(lora_new)
         metrics = _metrics(losses, per_client)
         return lora_new, opt_new, metrics, ef_new
 

@@ -376,28 +376,51 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def tick(self) -> int:
         """One engine step. Returns number of active slots. Idle ticks
-        (nothing queued or running) return 0 without touching the device."""
-        self._admit()
-        if self.paged:
-            self._ensure_decode_pages()
+        (nothing queued or running) return 0 without touching the device.
+        Each tick is a ``repro.tick`` host span on the profiler's clock
+        (recorded only while a profiler trace is on)."""
+        with jax.profiler.StepTraceAnnotation(
+                "repro.tick", step_num=self.ticks,
+                active=sum(s.req is not None for s in self.slots),
+                queued=self.scheduler.n_queued):
+            return self._tick()
+
+    def _tick(self) -> int:
+        with jax.profiler.TraceAnnotation("repro.tick.admit"):
+            self._admit()
+            if self.paged:
+                self._ensure_decode_pages()
         active = [i for i, s in enumerate(self.slots) if s.req is not None]
         if not active:
             self.ticks += 1          # the clock advances; the device idles
             return 0
         if self.paged:
-            self._push_table()
-        tokens = jnp.asarray(self.next_in)
-        if self.adapters is not None:
-            # the pool tree is re-read every tick, so pool.update()/sync
-            # between ticks hot-swaps weights with no engine involvement
-            lora = self.adapters.serving_lora(self.slot_rows)
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              tokens, lora)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              tokens)
+            with jax.profiler.TraceAnnotation("repro.tick.table"):
+                self._push_table()
+        with jax.profiler.TraceAnnotation("repro.tick.dispatch"):
+            tokens = jnp.asarray(self.next_in)
+            if self.adapters is not None:
+                # the pool tree is re-read every tick, so pool.update()/
+                # sync between ticks hot-swaps weights with no engine
+                # involvement
+                lora = self.adapters.serving_lora(self.slot_rows)
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  tokens, lora)
+            else:
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  tokens)
         self.device_steps += 1
-        logits_np = np.asarray(logits[:, -1, :self.cfg.vocab_size])
+        with jax.profiler.TraceAnnotation("repro.tick.readback"):
+            # the host waits here for the decode step to finish
+            logits_np = np.asarray(logits[:, -1, :self.cfg.vocab_size])
+        with jax.profiler.TraceAnnotation("repro.tick.sample"):
+            self._sample(active, logits_np)
+        self.ticks += 1
+        return len(active)
+
+    def _sample(self, active: list, logits_np: np.ndarray) -> None:
+        """Greedy next tokens for the active slots; finished requests
+        free their slots."""
         for i in active:
             s = self.slots[i]
             req = s.req
@@ -416,8 +439,6 @@ class ServeEngine:
                 req.done = True
                 self.scheduler.mark_done(req, self.ticks)
                 self._free_slot(i)               # freed immediately
-        self.ticks += 1
-        return len(active)
 
     def run(self, max_ticks: int = 10_000) -> None:
         """Tick until the queue and every slot drain. Returns immediately

@@ -119,33 +119,42 @@ def _apply_layer(p: dict, cfg: ModelConfig, spec: LayerSpec, x, *,
     # slice gathers on its own (measured 937 GB/step on gemma3 — §Perf)
     h = logical(h, "batch", *((None,) * (h.ndim - 1)))
     lo = lora or {}
-    if spec.kind == ATTN:
-        y = attn_mod.attn_forward(p["attn"], cfg, h, window=spec.window,
-                                  causal=True, lora=lo.get("attn"),
-                                  positions=positions)
-    elif spec.kind == CROSS:
-        y = attn_mod.attn_forward(p["attn"], cfg, h, memory=memory,
-                                  lora=lo.get("attn"))
-    elif spec.kind == RGLRU:
-        y = rglru_mod.rglru_forward(p["rglru"], cfg, h, lora=lo.get("rglru"))
-    elif spec.kind == MLSTM:
-        y = xlstm_mod.mlstm_forward(p["mlstm"], cfg, h, lora=lo.get("mlstm"))
-    elif spec.kind == SLSTM:
-        y = xlstm_mod.slstm_forward(p["slstm"], cfg, h, lora=lo.get("slstm"))
-    else:
-        raise ValueError(spec.kind)
+    # scopes name the sublayers in the compiled program's op names
+    with jax.named_scope(spec.kind):
+        if spec.kind == ATTN:
+            y = attn_mod.attn_forward(p["attn"], cfg, h, window=spec.window,
+                                      causal=True, lora=lo.get("attn"),
+                                      positions=positions)
+        elif spec.kind == CROSS:
+            y = attn_mod.attn_forward(p["attn"], cfg, h, memory=memory,
+                                      lora=lo.get("attn"))
+        elif spec.kind == RGLRU:
+            y = rglru_mod.rglru_forward(p["rglru"], cfg, h,
+                                        lora=lo.get("rglru"))
+        elif spec.kind == MLSTM:
+            y = xlstm_mod.mlstm_forward(p["mlstm"], cfg, h,
+                                        lora=lo.get("mlstm"))
+        elif spec.kind == SLSTM:
+            y = xlstm_mod.slstm_forward(p["slstm"], cfg, h,
+                                        lora=lo.get("slstm"))
+        else:
+            raise ValueError(spec.kind)
     x = x + y.astype(x.dtype)
     if encdec_cross and spec.kind == ATTN:
         h = rmsnorm(x, p["norm_cross"], cfg.norm_eps)
-        y = attn_mod.attn_forward(p["cross"], cfg, h, memory=memory,
-                                  lora=lo.get("cross"))
+        with jax.named_scope(CROSS):
+            y = attn_mod.attn_forward(p["cross"], cfg, h, memory=memory,
+                                      lora=lo.get("cross"))
         x = x + y.astype(x.dtype)
     if spec.ffn == DENSE:
         h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + mlp(p["ffn"], h, cfg.act).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            y = mlp(p["ffn"], h, cfg.act)
+        x = x + y.astype(x.dtype)
     elif spec.ffn == MOE:
         h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        y, a = moe_mod.moe_ffn(p["moe"], cfg, h)
+        with jax.named_scope("ffn"):
+            y, a = moe_mod.moe_ffn(p["moe"], cfg, h)
         x = x + y.astype(x.dtype)
         aux = aux + a
     return x, aux
@@ -284,22 +293,22 @@ def lm_loss(params: dict, cfg: ModelConfig, tokens: jax.Array,
     n_tok = targets.size
     lead = x.shape[:-2]
 
-    if S % C != 0 or S <= C:
-        total = _chunk_ce(x, targets, head, cfg)
-    else:
-        nc = S // C
-        xc = jnp.moveaxis(x.reshape(*lead, nc, C, x.shape[-1]), -3, 0)
-        tc = jnp.moveaxis(targets.reshape(*lead, nc, C), -2, 0)
+    with jax.named_scope("head"):
+        if S % C != 0 or S <= C:
+            total = _chunk_ce(x, targets, head, cfg)
+        else:
+            nc = S // C
+            xc = jnp.moveaxis(x.reshape(*lead, nc, C, x.shape[-1]), -3, 0)
+            tc = jnp.moveaxis(targets.reshape(*lead, nc, C), -2, 0)
 
-        @jax.checkpoint
-        def body(acc, inp):
-            xi, ti = inp
-            return acc + _chunk_ce(xi, ti, head, cfg), None
+            @jax.checkpoint
+            def body(acc, inp):
+                xi, ti = inp
+                return acc + _chunk_ce(xi, ti, head, cfg), None
 
-        total, _ = jax.lax.scan(
-            body, jnp.zeros(lead[:1], jnp.float32), (xc, tc))
-
-    ce = jnp.sum(total) / n_tok
+            total, _ = jax.lax.scan(
+                body, jnp.zeros(lead[:1], jnp.float32), (xc, tc))
+        ce = jnp.sum(total) / n_tok
     out = ce + aux, (ce, aux)
     if not per_client:
         return out

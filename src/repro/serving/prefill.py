@@ -73,6 +73,7 @@ class ChunkedPrefill:
             toks[0, :len(part)] = part
             args = (self.params, cache, toks, np.int32(slot),
                     np.int32(start), np.int32(n_pre))
-            cache = (self._step(*args) if lora is None
-                     else self._step_lora(*args, lora))
+            with jax.profiler.TraceAnnotation("repro.prefill"):
+                cache = (self._step(*args) if lora is None
+                         else self._step_lora(*args, lora))
         return cache

@@ -1,5 +1,5 @@
-"""Serving engine (single- and multi-adapter) + attribution + MoE dispatch
-equivalence tests."""
+"""Serving engine (single- and multi-adapter) + MoE dispatch equivalence
+tests."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -272,28 +272,6 @@ def test_moe_dispatch_equivalence(key):
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-5,
                                atol=2e-5)
     np.testing.assert_allclose(float(a1), float(a2), rtol=1e-6)
-
-
-def test_collective_attribution_parses():
-    from repro.roofline.attribution import attribute_collectives, format_table
-    hlo = """
-%body (p: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
-  %ag = f32[8,8]{1,0} all-gather(%x), dimensions={0}, metadata={op_name="jit(f)/while/dot_general"}
-  ROOT %t = tuple(...)
-}
-
-ENTRY %main (a: f32[8,8]) -> f32[8,8] {
-  %ar = f32[4,4]{1,0} all-reduce(%a), metadata={op_name="jit(f)/loss"}
-  %w = (s32[], f32[8,8]) while(%init), condition=%c, body=%body, backend_config={"known_trip_count":{"n":"5"}}
-  ROOT %r = f32[8,8] get-tuple-element(%w), index=1
-}
-"""
-    rows = attribute_collectives(hlo)
-    assert rows[0].kind == "all-gather"
-    assert rows[0].bytes_total == 5 * 256.0
-    assert rows[0].occurrences == 5
-    assert "dot_general" in rows[0].op_name
-    assert "GB" in format_table(rows)
 
 
 # ---------------------------------------------------------------------------
