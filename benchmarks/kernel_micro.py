@@ -109,9 +109,9 @@ def run(quick: bool = True, json_path: str | None = None):
 
     # flash_attention
     S = 512 if quick else 1024
-    q = jax.random.normal(k(5), (1, 4, S, 64), jnp.float32)
-    kk = jax.random.normal(k(6), (1, 4, S, 64), jnp.float32)
-    v = jax.random.normal(k(7), (1, 4, S, 64), jnp.float32)
+    q = jax.random.normal(k(5), (1, S, 4, 64), jnp.float32)
+    kk = jax.random.normal(k(6), (1, S, 4, 64), jnp.float32)
+    v = jax.random.normal(k(7), (1, S, 4, 64), jnp.float32)
     us = _time(lambda *t: ops.flash_attention(*t, causal=True), q, kk, v)
     flops = 2 * 2 * 4 * S * S * 64
     rows.append(("flash_attention", us,

@@ -13,7 +13,9 @@ Everything runs in this one process (a chip belongs to one process).
      to completion with no compile after warm-up.
   C. kernels: `gossip_mix_seg`, `slot_lora_matmul` and `paged_attn_decode`
      on the chip against their `kernels/ref.py` oracles at the widths the
-     phases used.
+     phases used; `flash_attention`'s output and gradients at gemma3-1b's
+     heads and at the benchmark cells' (the training phase's 64-token
+     sequences keep XLA's attention).
 
 With ``--chips 4`` only the sharded path runs: a one-process
 `ClusterSession` over the four local chips (client axis sharded) against a
@@ -29,6 +31,7 @@ non-zero before any phase.
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import os
@@ -61,6 +64,10 @@ TRAIN = DFLConfig(model="gemma3-1b", task="lm", reduced=False, n_clients=8,
 # (~3.9e-3); sums of such products stay under that in norm. 1e-2 leaves
 # 2.5x headroom; a wrong block, page or adapter row is off by O(1).
 KERNEL_RTOL = 1e-2
+# The same under "highest", where every kernel product takes float32
+# operands: only the order of float32 sums differs from the oracle
+# (~1e-6 relative); a product left at one bf16 pass reads ~3e-3.
+KERNEL_F32_RTOL = 1e-4
 # Sharded vs one-device gossip mix: the same kernel on the same columns,
 # only the column stripes differ between devices, so it agrees to f32
 # round-off.
@@ -210,7 +217,7 @@ def _check(name: str, got, want, rtol: float) -> None:
 
 def phase_kernels(session: Session, serving: ServingSession) -> None:
     cfg = session.model_cfg
-    keys = iter(jax.random.split(jax.random.key(7), 16))
+    keys = iter(jax.random.split(jax.random.key(7), 48))
     normal = lambda shape: jax.random.normal(next(keys), shape, jnp.float32)
 
     # gossip_mix_seg on the round's own flat layout and a real W_t
@@ -257,6 +264,40 @@ def phase_kernels(session: Session, serving: ServingSession) -> None:
             want = ref.paged_attn_decode_ref(q, kp, vp, table, lengths)
         _check(f"paged_attn_decode KV={n_kv} H={n_heads} hd={hd} "
                f"page_size={ps} pages={P}", got, want, KERNEL_RTOL)
+
+
+    # flash_attention, value and gradients: the model's heads past one
+    # 512-row block with and without its local window, and the qwen2-7b
+    # (also under "highest") and deepseek-moe-16b training cells' heads
+    local = next(ls.window for ls in cfg.pattern if ls.window)
+    gemma = (cfg.n_heads, cfg.n_kv_heads, cfg.hd)
+    for B, S, (H, KV, hd), window, precision in (
+            (1, 1024, gemma, local, "default"),
+            (1, 1024, gemma, None, "default"),
+            (8, 512, (28, 4, 128), None, "default"),
+            (8, 512, (28, 4, 128), None, "highest"),
+            (8, 256, (16, 16, 128), None, "default")):
+        if not ops.flash_attention_supported(S, S, hd):
+            raise AssertionError(f"[kernels] flash_attention does not take "
+                                 f"S={S} hd={hd}")
+        qkv = (normal((B, S, H, hd)), normal((B, S, KV, hd)),
+               normal((B, S, KV, hd)))
+        do = normal((B, S, H, hd))
+        with jax.default_matmul_precision(precision):
+            got = _value_and_vjp(functools.partial(
+                ops.flash_attention, window=window), qkv, do)
+        with jax.default_matmul_precision("highest"):
+            want = _value_and_vjp(functools.partial(
+                ref.flash_attention_ref, window=window), qkv, do)
+        rtol = KERNEL_F32_RTOL if precision == "highest" else KERNEL_RTOL
+        for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+            _check(f"flash_attention {name} B={B} S={S} H={H} KV={KV} "
+                   f"hd={hd} window={window} {precision}", g, w, rtol)
+
+
+def _value_and_vjp(f, args, cotangent) -> tuple:
+    out, vjp = jax.vjp(f, *args)
+    return (out,) + tuple(vjp(cotangent))
 
 
 # ---------------------------------------------------------------------------

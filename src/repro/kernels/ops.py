@@ -12,7 +12,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.dist.sharding import current_mesh
 from repro.kernels import ref
+from repro.kernels.flash_attention import block_sizes as _flash_blocks
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gossip_mix import gossip_mix as _gossip
 from repro.kernels.gossip_mix import gossip_mix_quant as _gossip_quant
@@ -72,13 +74,20 @@ def paged_attn_decode(q, k_pages, v_pages, table, lengths):
     return out.reshape(B, 1, H, hd)
 
 
+def flash_attention_supported(S: int, L: int, hd: int) -> bool:
+    """Whether `flash_attention` runs the kernel for causal attention of
+    S queries over L keys of width hd: the kernels run (TPU, or
+    interpreted), no multi-device mesh is bound (GSPMD cannot partition a
+    Mosaic call), heads fill whole lanes and the lengths tile."""
+    mesh = current_mesh()
+    return (_mode() != "ref" and (mesh is None or mesh.size == 1)
+            and hd % 128 == 0 and S == L and _flash_blocks(S, L) is not None)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
-                    window: Optional[int] = None, n_kv_heads: Optional[int] = None):
-    """q: (B, H, S, d); k/v: (B, KV, L, d) — GQA repeat handled here."""
-    if n_kv_heads and n_kv_heads != q.shape[1]:
-        rep = q.shape[1] // n_kv_heads
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
+                    window: Optional[int] = None):
+    """Differentiable attention. q: (B, S, H, d); k/v: (B, L, KV, d);
+    query head h reads kv head h // (H // KV)."""
     m = _mode()
     if m == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)

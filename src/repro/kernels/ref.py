@@ -41,12 +41,13 @@ def slot_lora_matmul_ref(x: jax.Array, w: jax.Array, a: jax.Array,
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True,
                         window: Optional[int] = None) -> jax.Array:
-    """Naive attention. q: (B, H, S, d), k/v: (B, H, L, d) (heads already
-    expanded — GQA repeat happens in ops)."""
-    B, H, S, d = q.shape
-    L = k.shape[2]
-    scores = jnp.einsum("bhsd,bhld->bhsl", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(float(d))
+    """Naive attention. q: (B, S, H, d); k/v: (B, L, KV, d); query head h
+    reads kv head h // (H // KV)."""
+    B, S, H, d = q.shape
+    L, KV = k.shape[1], k.shape[2]
+    qg = q.reshape(B, S, KV, H // KV, d)
+    scores = jnp.einsum("bskgd,blkd->bkgsl", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) / math.sqrt(d)
     q_pos = jnp.arange(S)[:, None]
     k_pos = jnp.arange(L)[None, :]
     mask = jnp.ones((S, L), bool)
@@ -56,8 +57,8 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
         mask &= q_pos - k_pos < window
     scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhsl,bhld->bhsd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = jnp.einsum("bkgsl,blkd->bskgd", probs, v.astype(jnp.float32))
+    return out.reshape(B, S, H, d).astype(q.dtype)
 
 
 def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,

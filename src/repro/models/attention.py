@@ -1,6 +1,10 @@
 """Grouped-query attention: global / sliding-window / cross, train + decode.
 
-Memory strategy (dry-run-safe at 32k prefill):
+Causal self-attention runs in the flash kernel where it can
+(`kernels.ops.flash_attention_supported`); the XLA core below serves the
+rest: cross-attention, bidirectional layers, the CPU.
+
+Memory strategy of the XLA core (dry-run-safe at 32k prefill):
  - queries are chunked with lax.scan when S >= _CHUNK_THRESHOLD;
  - chunk bodies are rematerialized (jax.checkpoint) so AD through the scan
    does not retain per-chunk score tensors;
@@ -155,15 +159,19 @@ def attn_forward(params: dict, cfg: ModelConfig, x: jax.Array, *,
         rep = lambda z: logical(z, "batch", *((None,) * (z.ndim - 1)))
         qf, kf, vf = rep(qf), rep(kf), rep(vf)
 
-    if memory is not None:
-        out = _attend(qf, kf, vf, None, cfg.n_kv_heads)
-    elif S < _CHUNK_THRESHOLD:
-        mask = _band_mask(jnp.arange(S), jnp.arange(L), causal=causal,
-                          window=window)
-        out = _attend(qf, kf, vf, mask[None, None, None], cfg.n_kv_heads)
-    else:
-        out = _chunked_attend(qf, kf, vf, cfg.n_kv_heads, causal=causal,
+    from repro.kernels import ops   # deferred: kernels import jax.pallas
+    with jax.named_scope("attn_core"):
+        if memory is not None:
+            out = _attend(qf, kf, vf, None, cfg.n_kv_heads)
+        elif causal and ops.flash_attention_supported(S, L, hd):
+            out = ops.flash_attention(qf, kf, vf, window=window)
+        elif S < _CHUNK_THRESHOLD:
+            mask = _band_mask(jnp.arange(S), jnp.arange(L), causal=causal,
                               window=window)
+            out = _attend(qf, kf, vf, mask[None, None, None], cfg.n_kv_heads)
+        else:
+            out = _chunked_attend(qf, kf, vf, cfg.n_kv_heads, causal=causal,
+                                  window=window)
 
     out = out.reshape(*lead, S, cfg.n_heads * hd)
     out = lora_linear(out, params["wo"], (lora or {}).get("wo"), scale)

@@ -139,6 +139,75 @@ def test_paged_attn_decode_compiles(topo, one_chip, n_kv, n_heads, hd):
     assert "tpu_custom_call" in hlo
 
 
+def test_flash_attention_grad_compiles_at_qwen2_7b_cell(topo, one_chip,
+                                                        pallas):
+    """The attention core of the qwen2-7b DFL cell (8 clients x 1 x 512
+    tokens, 28 query heads over 4 kv heads of 128) under value_and_grad:
+    forward and both backward kernels are Mosaic calls, and no
+    score-shaped float32 (..., heads, S, L) buffer is left to XLA."""
+    import re
+
+    B, S, H, KV, hd = 8, 512, 28, 4, 128
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, causal=True) ** 2)
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                   _spec((B, S, H, hd), jnp.float32, one_chip),
+                   _spec((B, S, KV, hd), jnp.float32, one_chip),
+                   _spec((B, S, KV, hd), jnp.float32, one_chip))
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    for name in ("flash_attn_fwd", "flash_attn_bwd_dq",
+                 "flash_attn_bwd_dkv"):
+        assert any(f"%{name}" in ln for ln in calls), name
+    assert not re.search(rf"f32\[\d+,\d+,[\d,]*{S},{S}\]", hlo)
+
+
+@pytest.mark.parametrize("B,S,H,KV,hd,window,precision", [
+    pytest.param(8, 256, 16, 16, 128, None, "default",
+                 id="deepseek-moe-16b"),
+    pytest.param(1, 1024, 4, 1, 256, 512, "default", id="gemma3-1b-local"),
+    pytest.param(1, 1024, 4, 1, 256, None, "default", id="gemma3-1b-global"),
+    pytest.param(1, 384, 28, 4, 128, None, "default", id="s384"),
+    pytest.param(1, 512, 28, 4, 128, 200, "highest", id="highest"),
+])
+def test_flash_attention_grad_compiles(B, S, H, KV, hd, window, precision,
+                                       topo, one_chip, pallas):
+    """The attention core under value_and_grad at the other shapes the
+    route sends to the kernels: the deepseek-moe-16b cell (MHA 16 x 128,
+    S=256 in 128-row sub-tiles), gemma3-1b's 256-wide heads past one block
+    with and without its 512-token window (grid-clamped blocks, masks
+    decided at run time), three sub-tiles a side, and float32 products
+    under "highest"."""
+    import re
+
+    assert ops.flash_attention_supported(S, S, hd)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, causal=True,
+                                           window=window) ** 2)
+
+    with jax.default_matmul_precision(precision):
+        hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                       _spec((B, S, H, hd), jnp.float32, one_chip),
+                       _spec((B, S, KV, hd), jnp.float32, one_chip),
+                       _spec((B, S, KV, hd), jnp.float32, one_chip))
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    for name in ("flash_attn_fwd", "flash_attn_bwd_dq",
+                 "flash_attn_bwd_dkv"):
+        assert any(f"%{name}" in ln for ln in calls), name
+    assert not re.search(rf"f32\[\d+,\d+,[\d,]*{S},{S}\]", hlo)
+
+
+def test_flash_attention_route_keeps_untiled_lengths_off_the_kernel(pallas):
+    """Lengths off the 128-row tiles (gemma3-1b's 64-token smoke batches,
+    an odd prompt), or past one 512-row block without filling the next,
+    and heads narrower than a lane keep the XLA core."""
+    assert ops.flash_attention_supported(512, 512, 128)
+    for S, hd in ((64, 256), (37, 128), (640, 128), (512, 64)):
+        assert not ops.flash_attention_supported(S, S, hd), (S, hd)
+
+
 def test_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
     """The DFL round with the flat gossip kernel, client axis sharded over
     a 4-chip mesh: the kernel must sit inside a shard_map (a Mosaic call

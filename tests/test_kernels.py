@@ -74,22 +74,122 @@ def test_slot_lora_matmul_matches_single_adapter(key):
                                       np.asarray(yi))
 
 
-@pytest.mark.parametrize("S,L,window,causal", [
-    (128, 128, None, True), (256, 256, 64, True), (128, 128, None, False),
-    (256, 256, 200, True),
+def _case(S, L, window, causal, heads=(3, 3), block=64, d=64, tag=""):
+    return pytest.param(S, L, window, causal, heads, block, d,
+                        id=f"{S}-{L}-{window}-{causal}{tag}")
+
+
+def _qkv(key, B, S, L, heads, d, dtype):
+    H, KV = heads
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (B, S, H, d), dtype),
+            jax.random.normal(ks[1], (B, L, KV, d), dtype),
+            jax.random.normal(ks[2], (B, L, KV, d), dtype))
+
+
+@pytest.mark.parametrize("S,L,window,causal,heads,block,d", [
+    _case(128, 128, None, True), _case(256, 256, 64, True),
+    _case(128, 128, None, False), _case(256, 256, 200, True),
+    # grouped-query heads (kv head h // G); blocks, and sub-tiles of the
+    # 256- and 512-row blocks, smaller than S, so that the fully-masked
+    # ones are skipped
+    _case(512, 512, None, True, (4, 1), 256, 128, "-gqa4x1"),
+    _case(256, 256, 96, True, (6, 2), 64, 64, "-gqa6x2"),
+    _case(512, 512, 200, True, (2, 2), 512, 128, "-mha2"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention(S, L, window, causal, dtype, key):
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (2, 3, S, 64), dtype)
-    k = jax.random.normal(ks[1], (2, 3, L, 64), dtype)
-    v = jax.random.normal(ks[2], (2, 3, L, 64), dtype)
-    y = flash_attention(q, k, v, causal=causal, window=window, bq=64, bk=64,
-                        interpret=True)
+def test_flash_attention(S, L, window, causal, heads, block, d, dtype, key):
+    """The kernel with products in the inputs' dtype (float32 ones
+    under "highest") against the oracle at the same precision."""
+    q, k, v = _qkv(key, 2, S, L, heads, d, dtype)
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        y = flash_attention(q, k, v, causal=causal, window=window, bq=block,
+                            bk=block, interpret=True)
     yr = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32),
                                rtol=_tol(dtype), atol=_tol(dtype) * 10)
+
+
+@pytest.mark.parametrize("S,window,heads,bq,bk", [
+    (128, None, (4, 4), 64, 64), (256, 64, (4, 2), 64, 128),
+    (512, None, (4, 1), 512, 512), (512, 200, (2, 2), 256, 256),
+])
+@pytest.mark.parametrize("precision,tol", [("highest", 1e-4),
+                                           ("default", 1e-2)])
+def test_flash_attention_grad(S, window, heads, bq, bk, precision, tol, key):
+    """o, dq, dk and dv of the custom VJP against the float32 oracle
+    differentiated at highest precision, as relative norms of the
+    difference: under "highest" the products take float32 operands and
+    agree to rounding; at the default (the chip's) they take bfloat16
+    ones and agree to their operand rounding."""
+    q, k, v = _qkv(key, 1, S, S, heads, 128, jnp.float32)
+    do = jax.random.normal(jax.random.fold_in(key, 1), q.shape)
+
+    def value_and_vjp(f):
+        o, vjp = jax.vjp(f, q, k, v)
+        return (o,) + vjp(do)
+
+    with jax.default_matmul_precision(precision):
+        got = value_and_vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, bq=bq, bk=bk,
+            interpret=True))
+    with jax.default_matmul_precision("highest"):
+        want = value_and_vjp(lambda q, k, v: ref.flash_attention_ref(
+            q, k, v, causal=True, window=window))
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.dtype == jnp.float32, name
+        gap = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert gap < tol, (name, gap)
+
+
+@pytest.mark.parametrize("case,routed", [
+    ("self", True), ("memory", False), ("bidirectional", False),
+    ("hd64", False), ("unaligned", False)])
+def test_attn_forward_routes_to_flash(case, routed, key, monkeypatch):
+    """Causal self-attention whose heads fill whole lanes and whose
+    lengths tile runs in the flash kernel when the kernels run (here
+    interpreted); cross-attention, bidirectional layers, narrow heads and
+    lengths off the 128-row tiles keep the XLA core. Either way the layer,
+    and its gradient, agree with the ref route to the kernel's bfloat16
+    products."""
+    import dataclasses
+
+    from repro.configs.base import ModelConfig
+    from repro.kernels import ops
+    from repro.models import attention as attn
+
+    cfg = ModelConfig(name="t", family="decoder", n_layers=1, d_model=256,
+                      n_heads=4, n_kv_heads=2, d_ff=512, vocab_size=256,
+                      head_dim=128, qkv_bias=True)
+    if case == "hd64":
+        cfg = dataclasses.replace(cfg, head_dim=64)
+    ks = jax.random.split(key, 3)
+    params = attn.init_attn(ks[0], cfg)
+    x = jax.random.normal(ks[1], (2, 96 if case == "unaligned" else 128,
+                                  cfg.d_model))
+    kw = {"memory": jax.random.normal(ks[2], (2, 128, cfg.d_model))} \
+        if case == "memory" else {"causal": case != "bidirectional"}
+
+    def loss(x):
+        y = attn.attn_forward(params, cfg, x, **kw)
+        return jnp.sum(jnp.sin(y)), y
+
+    calls = []
+    kernel = ops.flash_attention
+    monkeypatch.setattr(ops, "flash_attention",
+                        lambda *a, **k: calls.append(1) or kernel(*a, **k))
+    try:
+        ops.set_backend("pallas_interpret")
+        (_, y), dx = jax.value_and_grad(loss, has_aux=True)(x)
+    finally:
+        ops.set_backend(None)
+    assert bool(calls) == routed
+    (_, y_ref), dx_ref = jax.value_and_grad(loss, has_aux=True)(x)
+    for got, want in ((y, y_ref), (dx, dx_ref)):
+        gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        assert gap < (1e-2 if routed else 1e-6), gap
 
 
 @pytest.mark.parametrize("m,P", [(10, 512), (16, 2048), (4, 1024)])
