@@ -37,9 +37,9 @@ def model_100m():
 def perplexity(base, cfg, lora, batches):
     tot, n = 0.0, 0
     for b in batches:
-        loss, (ce, _) = tf.lm_loss(base, cfg, jnp.asarray(b["tokens"]),
-                                   jnp.asarray(b["targets"]), lora=lora,
-                                   remat=False)
+        loss, (ce, *_) = tf.lm_loss(base, cfg, jnp.asarray(b["tokens"]),
+                                    jnp.asarray(b["targets"]), lora=lora,
+                                    remat=False)
         tot += float(ce)
         n += 1
     return float(np.exp(tot / n))

@@ -186,11 +186,13 @@ def _build(cfg: DFLConfig, model_cfg, loss_fn) -> _Built:
         base = tf.init_params(base_key, mc)
         if loss_fn is None:
             def loss_fn(bp, lo, micro, _cfg=mc):
-                out, per = tf.lm_loss(bp, _cfg, micro["tokens"],
-                                      micro["targets"],
-                                      frontend=micro.get("frontend"),
-                                      lora=lo, per_client=True)
-                return out[0], per
+                (loss, (_, _, load)), per = tf.lm_loss(
+                    bp, _cfg, micro["tokens"], micro["targets"],
+                    frontend=micro.get("frontend"), lora=lo,
+                    per_client=True)
+                if load is None:
+                    return loss, per
+                return loss, per, {"expert_load": load}
     else:
         from repro.models.classifier import (classifier_accuracy,
                                              classifier_loss, encoder_config,

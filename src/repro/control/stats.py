@@ -60,6 +60,7 @@ class RoundStats:
         self._loss_pc: Optional[np.ndarray] = None
         self._consensus: Optional[dict] = None
         self._w_gap: Optional[float] = None
+        self._imbalance: Optional[np.ndarray] = None
 
     # -- losses -------------------------------------------------------------
     @property
@@ -85,6 +86,22 @@ class RoundStats:
             a = np.asarray(pc, np.float32)      # (local_steps, m)
             self._loss_pc = a.mean(axis=0, dtype=np.float32)
         return self._loss_pc
+
+    # -- MoE routing --------------------------------------------------------
+    @property
+    def expert_load_imbalance(self) -> Optional[np.ndarray]:
+        """(MoE layers,) max over mean of the (token, expert) pairs routed
+        to each expert in the round (the metric ``expert_load``, summed
+        over the local steps); 1.0 is an even load. None without MoE
+        layers."""
+        load = self.metrics.get("expert_load") \
+            if hasattr(self.metrics, "get") else None
+        if load is None:
+            return None
+        if self._imbalance is None:
+            a = np.asarray(load, np.float64)    # (MoE layers, E)
+            self._imbalance = a.max(axis=-1) / a.mean(axis=-1)
+        return self._imbalance
 
     # -- mixing / consensus -------------------------------------------------
     def w_gap(self) -> float:

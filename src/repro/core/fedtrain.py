@@ -58,6 +58,9 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
       (scalar loss, per_client_vec) — the vector (shard-local entries)
       is surfaced as metrics["loss_per_client"] for grid-invariant loss
       reporting; scalar-only loss_fns report through a length-1 vector.
+      A third element, a dict of counters (e.g. the MoE layers'
+      "expert_load"), is summed over the local steps into the metrics
+      under the same names, on the device.
       microbatch carries the per-client batch (leading client axis matching
       the LoRA client axis).
 
@@ -131,30 +134,31 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                 # a length-1 vector — reporting then equals the scalar)
                 with jax.named_scope("loss"):
                     out = loss_fn(base_params, l, micro)
-                if isinstance(out, tuple):
-                    return out
-                return out, jnp.reshape(out, (1,))
+                if not isinstance(out, tuple):
+                    return out, (jnp.reshape(out, (1,)), {})
+                return out[0], (out[1], out[2] if len(out) > 2 else {})
 
-            (loss, per), grads = jax.value_and_grad(
+            (loss, (per, counters)), grads = jax.value_and_grad(
                 objective, has_aux=True)(lo)
             with jax.named_scope("opt"):
                 lo, opt = optimizer.update(grads, opt, lo,
                                            update_mask=mask_fn)
             lo = shard_lora_tree(lo)
-            return (lo, opt), (loss, per)
+            return (lo, opt), (loss, per, counters)
 
         return jax.lax.scan(local_step, (lora, opt_state), batch)
 
-    def _metrics(losses, per_client):
+    def _metrics(losses, per_client, counters):
         # loss_per_client (local_steps, n) is replicated so every process
         # can host-read it: the session reduces it in ONE fixed order, so
         # the reported loss is bitwise identical across process grids
         # (the in-graph scalars may reduce in a grid-dependent order)
         return {"loss": jnp.mean(losses), "loss_per_step": losses,
-                "loss_per_client": replicated(per_client)}
+                "loss_per_client": replicated(per_client),
+                **{k: jnp.sum(v, axis=0) for k, v in counters.items()}}
 
     def round_fn(base_params, lora, opt_state: AdamWState, batch, W, masks):
-        (lora_new, opt_new), (losses, per_client) = _local_phase(
+        (lora_new, opt_new), (losses, per_client, counters) = _local_phase(
             base_params, lora, opt_state, batch, masks)
 
         # Joint mixing (Algorithm 1 lines 7–9): masks select per method.
@@ -173,12 +177,12 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                                else None),
                     flat_lowering=mix_flat_lowering)
             lora_new = shard_lora_tree(lora_new)
-        metrics = _metrics(losses, per_client)
+        metrics = _metrics(losses, per_client, counters)
         return lora_new, opt_new, metrics
 
     def round_fn_quant(base_params, lora, opt_state: AdamWState, batch, W,
                        masks, ef):
-        (lora_new, opt_new), (losses, per_client) = _local_phase(
+        (lora_new, opt_new), (losses, per_client, counters) = _local_phase(
             base_params, lora, opt_state, batch, masks)
 
         with jax.named_scope("mix"):
@@ -187,7 +191,7 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                 lora_prev=(lora if mix_comm == "sparse_overlap" else None),
                 flat_lowering=mix_flat_lowering, quant=mix_quant, ef=ef)
             lora_new = shard_lora_tree(lora_new)
-        metrics = _metrics(losses, per_client)
+        metrics = _metrics(losses, per_client, counters)
         return lora_new, opt_new, metrics, ef_new
 
     if mix_quant != "off":

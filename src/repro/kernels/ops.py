@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.dist.sharding import current_mesh
 from repro.kernels import ref
+from repro.kernels.expert_matmul import expert_matmul as _expert_mm
 from repro.kernels.flash_attention import block_sizes as _flash_blocks
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gossip_mix import gossip_mix as _gossip
@@ -93,6 +94,29 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return _flash(q, k, v, causal=causal, window=window,
                   interpret=(m == "interpret"))
+
+
+def expert_matmul_supported(K: int, N: int) -> bool:
+    """Whether `expert_matmul` runs the kernel for experts of K x N: the
+    kernels run (TPU, or interpreted), no multi-device mesh is bound
+    (GSPMD cannot partition a Mosaic call) and both widths fill whole
+    lanes."""
+    mesh = current_mesh()
+    return (_mode() != "ref" and (mesh is None or mesh.size == 1)
+            and K % 128 == 0 and N % 128 == 0)
+
+
+def expert_matmul(x, w, group_sizes, layer=None):
+    """Grouped expert matmul, differentiable in x. x: (M, K) rows sorted
+    by expert; w: (E, K, N), or (L, E, K, N) read at ``layer``;
+    group_sizes: (E,) int32 summing to M. Where the kernel does not run,
+    XLA's `ragged_dot`."""
+    m = _mode()
+    if not expert_matmul_supported(*w.shape[-2:]):
+        return jax.lax.ragged_dot(x, w if layer is None else w[layer],
+                                  group_sizes)
+    return _expert_mm(x, w, group_sizes, layer,
+                      interpret=(m == "interpret"))
 
 
 def gossip_mix_flat(w: jax.Array, x: jax.Array, mask: jax.Array | float = 1.0):

@@ -139,3 +139,18 @@ def rglru_scan_ref(a: jax.Array, u: jax.Array) -> jax.Array:
     _, hs = jax.lax.scan(step, h0, (jnp.moveaxis(a32, 1, 0),
                                     jnp.moveaxis(u32, 1, 0)))
     return jnp.moveaxis(hs, 0, 1).astype(u.dtype)
+
+
+def expert_matmul_ref(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                      *, transpose: bool = False) -> jax.Array:
+    """Rows sorted by expert, one expert at a time: row i of group e is
+    x[i] @ w[e] (x[i] @ w[e].T with ``transpose``); rows past the groups
+    are zero. x: (M, K), w: (E, K, N) (or (E, N, K)), group_sizes: (E,)."""
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(x.shape[0])[:, None]
+    out = 0.0
+    for e in range(w.shape[0]):
+        we = w[e].T if transpose else w[e]
+        mine = (rows >= ends[e] - group_sizes[e]) & (rows < ends[e])
+        out = out + jnp.where(mine, x @ we.astype(x.dtype), 0.0)
+    return jnp.asarray(out, jnp.float32).astype(x.dtype)

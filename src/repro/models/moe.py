@@ -1,26 +1,77 @@
 """Mixture-of-Experts FFN: routed top-k + optional shared experts
 (DeepSeekMoE / Moonlight fine-grained style; Mixtral when shared=0).
 
-Dense-einsum formulation: every expert runs on every token, gated by the
-router's top-k weights. This is the standard TPU-friendly dense-MoE lowering
-(no gather/scatter data-dependence; FLOPs are dense but the *routing math*
-and load-balance aux loss are faithful). Expert weights are stacked
-(E, d, e_ff) and shard e_ff over the "model" axis (expert-tensor parallel) +
-d over "fsdp" — expert counts (8, 64) need not divide the mesh.
+The router (float32 products, softmax over every expert, top-k weights
+renormalised) and the Switch balance loss are shared by every dispatch.
+Expert weights are stacked (E, d, e_ff). The dispatch follows where they
+live (`_resolve`):
 
-A `dispatch="fused"` variant folds combine weights into the down-projection
-contraction (no per-expert output tensor) — kept for §Perf comparison:
-identical numerics, different lowering.
+- ``routed`` where no multi-device mesh is bound (one device holds
+  every expert and every token): the (token, expert) pairs are sorted by
+  expert, the grouped expert matmuls (`kernels.ops.expert_matmul`) run
+  each expert on its own rows only, and the rows are gathered back to
+  their tokens and summed under their gates. Every leading axis is
+  routed together (all clients' tokens of a local step).
+- ``dense`` on a multi-device mesh, whether it shards the experts over
+  "model" or the clients' tokens over their own axis: every expert runs
+  on every token, gated — no data-dependent sort or gather, so GSPMD
+  keeps each device's tokens local and partitions the experts as dense
+  expert parallelism. ``fused`` (`set_dispatch`, pinned by
+  `launch/dryrun.py`) folds the gates into the down-projection
+  contraction (no per-expert output tensor): identical numerics,
+  different lowering.
+
+Scopes ``router``, ``dispatch``, ``experts``, ``combine`` and ``shared``
+name the phases in the compiled program.
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist.sharding import logical
+from repro.dist.sharding import axis_size, current_mesh, logical
+from repro.kernels import ops
 from repro.models.common import dense_init, init_mlp, mlp
 from repro.models.layers import shard_act
+
+_ROUTER_PRECISION = jax.lax.Precision.HIGHEST
+_DISPATCH = ["dense"]   # multi-device meshes; launch code overrides
+_EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+class Stacked(NamedTuple):
+    """Layer ``layer`` of the experts of every scanned layer, ``stack``
+    (layers, E, ...): the grouped matmuls read the layer's blocks from the
+    stack, where a slice handed to a kernel would be copied whole."""
+    stack: jax.Array
+    layer: jax.Array
+
+
+def pull_experts(layer_params: dict):
+    """A scanned pattern position's params (leading layer axis) split into
+    (the rest, its stacked expert weights or None)."""
+    moe = layer_params.get("moe")
+    if moe is None:
+        return layer_params, None
+    rest = {k: v for k, v in moe.items() if k not in _EXPERTS}
+    return {**layer_params, "moe": rest}, \
+        {k: moe[k] for k in _EXPERTS}
+
+
+def put_experts(layer_params: dict, stacks, layer) -> dict:
+    """One layer's params with its expert weights as `Stacked` views."""
+    if stacks is None:
+        return layer_params
+    views = {k: Stacked(v, layer) for k, v in stacks.items()}
+    return {**layer_params, "moe": {**layer_params["moe"], **views}}
+
+
+def _layer(w):
+    """The layer's own (E, ...) expert weights."""
+    return w.stack[w.layer] if isinstance(w, Stacked) else w
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
@@ -41,33 +92,43 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     return p
 
 
-def router_probs(params: dict, cfg: ModelConfig, x: jax.Array):
-    """Returns (combine_weights (..., E), aux_loss scalar)."""
+def _route(params: dict, cfg: ModelConfig, x: jax.Array):
+    """Softmax router over every expert: (probs (..., E), top-k weights
+    renormalised to sum to one (..., k), their experts (..., k)). The
+    logits are float32 products: a one-pass bfloat16 router flips the
+    choice of near-tied experts."""
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
+                        params["router"].astype(jnp.float32),
+                        precision=_ROUTER_PRECISION)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
     top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)   # renormalize
-    combine = jnp.zeros_like(probs)
-    combine = jnp.put_along_axis(combine, top_idx, top_w, axis=-1,
-                                 inplace=False)
-    # Switch-style load-balance loss: E * sum_e f_e * P_e
-    E = cfg.n_experts
+    return probs, top_w, top_idx
+
+
+def _balance_loss(probs, top_w, top_idx, E: int):
+    """Switch-style load-balance loss E * sum_e f_e * P_e over every token
+    (f_e: share of tokens that chose e, P_e: mean router probability)."""
+    chose = jnp.sum(jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
+                    * (top_w > 0)[..., None], axis=-2)
     dims = tuple(range(probs.ndim - 1))
-    frac_tokens = jnp.mean((combine > 0).astype(jnp.float32), axis=dims)
-    frac_probs = jnp.mean(probs, axis=dims)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return combine.astype(x.dtype), aux
-
-
-from repro.dist.sharding import axis_size
-
-_DISPATCH = ["dense"]   # module default; launch code overrides
+    return E * jnp.sum(jnp.mean(chose, axis=dims) * jnp.mean(probs, axis=dims))
 
 
 def set_dispatch(mode: str) -> None:
+    """Dispatch on every multi-device mesh (`launch/dryrun.py`)."""
     assert mode in ("dense", "fused"), mode
     _DISPATCH[0] = mode
+
+
+def _resolve(dispatch: str | None) -> str:
+    """Routed on one device; on a multi-device mesh the pinned dense
+    lowering, since routed's sort and gather over every token would cross
+    the devices that shard the clients or the experts."""
+    if dispatch is not None:
+        return dispatch
+    mesh = current_mesh()
+    return "routed" if mesh is None or mesh.size == 1 else _DISPATCH[0]
 
 
 def _hg_spec(E: int, ndim: int):
@@ -82,32 +143,79 @@ def _hg_spec(E: int, ndim: int):
     return names
 
 
-def moe_ffn(params: dict, cfg: ModelConfig, x: jax.Array,
-            dispatch: str | None = None):
-    """x: (..., d) -> (out (..., d), aux_loss)."""
-    dispatch = dispatch or _DISPATCH[0]
-    combine, aux = router_probs(params, cfg, x)
-    if dispatch == "dense":
-        # every expert everywhere, gated: (..., E, e_ff)
-        hg = jnp.einsum("...d,edf->...ef", x, params["w_gate"])
-        hu = jnp.einsum("...d,edf->...ef", x, params["w_up"])
-        hg = logical(hg, *_hg_spec(cfg.n_experts, hg.ndim))
-        h = jax.nn.silu(hg) * hu
-        per_exp = jnp.einsum("...ef,efd->...ed", h, params["w_down"])
-        out = jnp.einsum("...ed,...e->...d", per_exp, combine)
-    elif dispatch == "fused":
+def _routed(params: dict, cfg: ModelConfig, x: jax.Array, top_w, top_idx,
+            group_sizes):
+    """Each token through its top-k experts only. The (token, expert)
+    pairs of every leading index are sorted by expert, the grouped
+    matmuls run each expert on its rows, and the rows come back to their
+    tokens weighted by the gates."""
+    d, k = x.shape[-1], cfg.top_k
+    xt = x.reshape(-1, d)
+    with jax.named_scope("dispatch"):
+        order = jnp.argsort(top_idx.reshape(-1))
+        back = jnp.argsort(order)
+        xs = jnp.repeat(xt, k, axis=0).at[order].get(unique_indices=True)
+    with jax.named_scope("experts"):
+        def matmul(rows, name):
+            w = params[name]
+            if isinstance(w, Stacked):
+                return ops.expert_matmul(rows, w.stack, group_sizes, w.layer)
+            return ops.expert_matmul(rows, w, group_sizes)
+
+        h = jax.nn.silu(matmul(xs, "w_gate")) * matmul(xs, "w_up")
+        y = matmul(h, "w_down")
+    with jax.named_scope("combine"):
+        y = y.at[back].get(unique_indices=True).reshape(-1, k, d)
+        out = jnp.sum(y * top_w.reshape(-1, k, 1).astype(y.dtype), axis=1)
+    return out.reshape(x.shape)
+
+
+def _dense(params: dict, cfg: ModelConfig, x: jax.Array, combine,
+           fused: bool):
+    """Every expert on every token, gated by ``combine`` (..., E)."""
+    w_gate, w_up, w_down = (_layer(params[k]) for k in _EXPERTS)
+    hg = jnp.einsum("...d,edf->...ef", x, w_gate)
+    hu = jnp.einsum("...d,edf->...ef", x, w_up)
+    hg = logical(hg, *_hg_spec(cfg.n_experts, hg.ndim))
+    h = jax.nn.silu(hg) * hu
+    if fused:
         # fold the combine weight into the down-projection contraction: the
         # (..., E, d) per-expert output tensor (the §Perf-measured memory
         # bomb: 17 GB/device for moonshot train_4k) never materializes, and
         # with expert-sharded weights the contraction over E psums across
         # the model axis — dense expert parallelism.
-        hg = jnp.einsum("...d,edf->...ef", x, params["w_gate"])
-        hu = jnp.einsum("...d,edf->...ef", x, params["w_up"])
-        hg = logical(hg, *_hg_spec(cfg.n_experts, hg.ndim))
-        h = jax.nn.silu(hg) * hu * combine[..., None].astype(x.dtype)
-        out = jnp.einsum("...ef,efd->...d", h, params["w_down"])
+        h = h * combine[..., None].astype(x.dtype)
+        return jnp.einsum("...ef,efd->...d", h, w_down)
+    per_exp = jnp.einsum("...ef,efd->...ed", h, w_down)
+    return jnp.einsum("...ed,...e->...d", per_exp, combine)
+
+
+def moe_layer(params: dict, cfg: ModelConfig, x: jax.Array,
+              dispatch: str | None = None):
+    """x: (..., d) -> (out (..., d), aux_loss, load (E,) int32: the
+    (token, expert) pairs routed to each expert)."""
+    dispatch = _resolve(dispatch)
+    with jax.named_scope("router"):
+        probs, top_w, top_idx = _route(params, cfg, x)
+        aux = _balance_loss(probs, top_w, top_idx, cfg.n_experts)
+        load = jnp.bincount(top_idx.reshape(-1), length=cfg.n_experts
+                            ).astype(jnp.int32)
+    if dispatch == "routed":
+        out = _routed(params, cfg, x, top_w, top_idx, load)
+    elif dispatch in ("dense", "fused"):
+        combine = jnp.put_along_axis(jnp.zeros_like(probs), top_idx, top_w,
+                                     axis=-1, inplace=False).astype(x.dtype)
+        out = _dense(params, cfg, x, combine, fused=dispatch == "fused")
     else:
         raise ValueError(dispatch)
     if cfg.n_shared_experts:
-        out = out + mlp(params["shared"], x)
-    return shard_act(out), aux * cfg.router_aux_coef
+        with jax.named_scope("shared"):
+            out = out + mlp(params["shared"], x)
+    return shard_act(out), aux * cfg.router_aux_coef, load
+
+
+def moe_ffn(params: dict, cfg: ModelConfig, x: jax.Array,
+            dispatch: str | None = None):
+    """x: (..., d) -> (out (..., d), aux_loss)."""
+    out, aux, _ = moe_layer(params, cfg, x, dispatch)
+    return out, aux

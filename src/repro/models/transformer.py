@@ -73,17 +73,16 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     encdec = cfg.family == "encdec"
 
     # scanned groups: per pattern position, leaves stacked (n_groups, ...)
-    groups = []
-    for j, spec in enumerate(cfg.pattern):
-        per_group = [
-            _init_layer(jax.random.fold_in(kG, j * 1000 + g), cfg, spec,
-                        dtype, encdec)
-            for g in range(cfg.n_groups)
-        ]
-        groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
-                      if cfg.n_groups > 1 else
-                      jax.tree.map(lambda x: x[None], per_group[0]))
-    params["groups"] = groups
+    # and drawn straight into the stack, layer g from fold_in(kG, j*1000+g):
+    # per-layer leaves stacked afterwards held the layers twice. The key
+    # is an argument, not a constant of the program, so one compiled
+    # program serves every seed
+    def group(j, spec):
+        return jax.jit(jax.vmap(lambda k, g: _init_layer(
+            jax.random.fold_in(k, j * 1000 + g), cfg, spec, dtype,
+            encdec), in_axes=(None, 0)))(kG, jnp.arange(cfg.n_groups))
+
+    params["groups"] = [group(j, spec) for j, spec in enumerate(cfg.pattern)]
     params["tail"] = [
         _init_layer(jax.random.fold_in(kT, j), cfg, spec, dtype, encdec)
         for j, spec in enumerate(cfg.tail_pattern)
@@ -112,7 +111,9 @@ def param_specs(cfg: ModelConfig, dtype=jnp.float32):
 
 def _apply_layer(p: dict, cfg: ModelConfig, spec: LayerSpec, x, *,
                  memory, positions, lora: Optional[dict], encdec_cross: bool):
+    """One layer: (x, balance loss, expert load (E,) or None)."""
     aux = jnp.zeros((), jnp.float32)
+    load = None
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     # under sequence parallelism, re-materialize the full sequence ONCE per
     # sublayer here (Megatron-SP all-gather point); otherwise each q-chunk
@@ -154,10 +155,10 @@ def _apply_layer(p: dict, cfg: ModelConfig, spec: LayerSpec, x, *,
     elif spec.ffn == MOE:
         h = rmsnorm(x, p["norm2"], cfg.norm_eps)
         with jax.named_scope("ffn"):
-            y, a = moe_mod.moe_ffn(p["moe"], cfg, h)
+            y, a, load = moe_mod.moe_layer(p["moe"], cfg, h)
         x = x + y.astype(x.dtype)
         aux = aux + a
-    return x, aux
+    return x, aux, load
 
 
 def _encoder_forward(params: dict, cfg: ModelConfig, frontend: jax.Array,
@@ -180,6 +181,31 @@ def hidden_forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
                    frontend: Optional[jax.Array] = None,
                    lora: Optional[dict] = None, remat: bool = True):
     """Backbone only: returns (hidden (..., S, d) post-final-norm, aux)."""
+    x, aux, _ = _backbone(params, cfg, tokens, frontend=frontend, lora=lora,
+                          remat=remat)
+    return x, aux
+
+
+def _scanned(groups: list):
+    """The scanned groups' params as scan inputs, and ``layer_params(xs,
+    j, i)``: pattern position j's params at scan step i. Expert weights
+    stay whole outside the scanned slices and each layer's grouped
+    matmuls read their blocks from the stack (`moe.Stacked`): a slice
+    handed to a Mosaic call is copied whole."""
+    split = [moe_mod.pull_experts(p) for p in groups]
+    stacks = [s[1] for s in split]
+
+    def layer_params(xs, j, i):
+        return moe_mod.put_experts(xs[j], stacks[j], i)
+
+    return [s[0] for s in split], layer_params
+
+
+def _backbone(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
+              frontend: Optional[jax.Array], lora: Optional[dict],
+              remat: bool):
+    """(hidden, balance loss, expert load (MoE layers, E) int32 in layer
+    order, or None without MoE layers)."""
     x = embed_tokens(params["embed"], tokens) * math.sqrt(cfg.d_model)
     x = shard_act(x, None)
     S = tokens.shape[-1]
@@ -197,33 +223,44 @@ def hidden_forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     aux_total = jnp.zeros((), jnp.float32)
 
     # --- scanned pattern groups ---
+    group_params, layer_params = _scanned(params["groups"])
+
     def group_body(carry, xs):
         x, aux = carry
+        loads = []
         for j, spec in enumerate(cfg.pattern):
-            x, a = _apply_layer(xs[0][j], cfg, spec, x, memory=memory,
-                                positions=positions,
-                                lora=xs[1][j] if xs[1] is not None else None,
-                                encdec_cross=encdec)
+            x, a, load = _apply_layer(
+                layer_params(xs[0], j, xs[2]), cfg, spec, x, memory=memory,
+                positions=positions,
+                lora=xs[1][j] if xs[1] is not None else None,
+                encdec_cross=encdec)
             aux = aux + a
-        return (x, aux), None
+            if load is not None:
+                loads.append(load)
+        return (x, aux), (jnp.stack(loads) if loads else None)
 
     body = jax.checkpoint(group_body) if remat else group_body
     has_lora = any(g is not None for g in lo_groups)
-    xs = (params["groups"], lo_groups if has_lora else None)
-    (x, aux_total), _ = jax.lax.scan(
+    xs = (group_params, lo_groups if has_lora else None,
+          jnp.arange(cfg.n_groups))
+    (x, aux_total), group_loads = jax.lax.scan(
         body, (x, aux_total), xs,
         length=cfg.n_groups)
+    loads = [] if group_loads is None else \
+        [group_loads.reshape(-1, group_loads.shape[-1])]
 
     # --- tail layers ---
     lo_tail = lo.get("tail", [None] * cfg.tail_len)
     for j, spec in enumerate(cfg.tail_pattern):
-        x, a = _apply_layer(params["tail"][j], cfg, spec, x, memory=memory,
-                            positions=positions, lora=lo_tail[j],
-                            encdec_cross=encdec)
+        x, a, load = _apply_layer(params["tail"][j], cfg, spec, x,
+                                  memory=memory, positions=positions,
+                                  lora=lo_tail[j], encdec_cross=encdec)
         aux_total = aux_total + a
+        if load is not None:
+            loads.append(load[None])
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux_total
+    return x, aux_total, (jnp.concatenate(loads) if loads else None)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
@@ -278,15 +315,19 @@ def lm_loss(params: dict, cfg: ModelConfig, tokens: jax.Array,
     262k) are never resident — the fix for the 210 GB/device dry-run bomb
     (EXPERIMENTS.md §Perf notes).
 
+    Returns (loss, (ce, aux, expert_load)): the MoE balance loss and the
+    (token, expert) pairs routed to each expert of each MoE layer
+    ((MoE layers, E) int32; None without MoE layers).
+
     CE accumulates per-leading-index (per-client) partial sums; the
     scalar is their flat combine. With ``per_client`` the return is
-    ((loss, aux-tuple), per_client_mean_vec): the vector entries are
+    ((loss, parts), per_client_mean_vec): the vector entries are
     shard-local, hence bitwise identical on every process grid — the DFL
     round reports loss from it host-side while the scalar feeds only the
     gradient (the MoE aux term keeps its plain mean; MoE archs are
     outside the multihost parity surface)."""
-    x, aux = hidden_forward(params, cfg, tokens, frontend=frontend,
-                            lora=lora, remat=remat)
+    x, aux, load = _backbone(params, cfg, tokens, frontend=frontend,
+                             lora=lora, remat=remat)
     head = params.get("unembed", params["embed"])
     S = x.shape[-2]
     C = min(_CE_CHUNK, S)
@@ -309,7 +350,7 @@ def lm_loss(params: dict, cfg: ModelConfig, tokens: jax.Array,
             total, _ = jax.lax.scan(
                 body, jnp.zeros(lead[:1], jnp.float32), (xc, tc))
         ce = jnp.sum(total) / n_tok
-    out = ce + aux, (ce, aux)
+    out = ce + aux, (ce, aux, load)
     if not per_client:
         return out
     vec = total / (n_tok // total.shape[0]) if total.ndim \
@@ -466,19 +507,21 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     lo = lora or {}
     lo_groups = lo.get("groups", [None] * len(cfg.pattern))
     has_lora = any(g is not None for g in lo_groups)
+    group_params, layer_params = _scanned(params["groups"])
 
     def body(x, xs):
-        gp, gc, gl = xs
+        gp, gc, gl, i = xs
         new_gc = []
         for j, spec in enumerate(cfg.pattern):
-            x, nc = _decode_layer(gp[j], cfg, spec, x, gc[j],
+            x, nc = _decode_layer(layer_params(gp, j, i), cfg, spec, x,
+                                  gc[j],
                                   lora=gl[j] if gl is not None else None,
                                   encdec_cross=encdec, pages=pages)
             new_gc.append(nc)
         return x, new_gc
 
-    xs = (params["groups"], cache["groups"],
-          lo_groups if has_lora else None)
+    xs = (group_params, cache["groups"],
+          lo_groups if has_lora else None, jnp.arange(cfg.n_groups))
     x, new_group_caches = jax.lax.scan(body, x, xs, length=cfg.n_groups)
 
     lo_tail = lo.get("tail", [None] * cfg.tail_len)
@@ -559,19 +602,20 @@ def chunk_prefill_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     lo = lora or {}
     lo_groups = lo.get("groups", [None] * len(cfg.pattern))
     has_lora = any(g is not None for g in lo_groups)
+    group_params, layer_params = _scanned(params["groups"])
 
     def body(x, xs):
-        gp, gc, gl = xs
+        gp, gc, gl, i = xs
         new_gc = []
         for j, spec in enumerate(cfg.pattern):
             x, nc = _chunk_prefill_layer(
-                gp[j], cfg, spec, x, gc[j], slot, start, limit,
-                lora=gl[j] if gl is not None else None, pages=pages)
+                layer_params(gp, j, i), cfg, spec, x, gc[j], slot, start,
+                limit, lora=gl[j] if gl is not None else None, pages=pages)
             new_gc.append(nc)
         return x, new_gc
 
-    xs = (params["groups"], cache["groups"],
-          lo_groups if has_lora else None)
+    xs = (group_params, cache["groups"],
+          lo_groups if has_lora else None, jnp.arange(cfg.n_groups))
     x, new_group_caches = jax.lax.scan(body, x, xs, length=cfg.n_groups)
 
     lo_tail = lo.get("tail", [None] * cfg.tail_len)

@@ -208,11 +208,9 @@ def test_flash_attention_route_keeps_untiled_lengths_off_the_kernel(pallas):
         assert not ops.flash_attention_supported(S, S, hd), (S, hd)
 
 
-def test_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
-    """The DFL round with the flat gossip kernel, client axis sharded over
-    a 4-chip mesh: the kernel must sit inside a shard_map (a Mosaic call
-    cannot be partitioned automatically)."""
-    cfg = get_config("gemma3-1b").reduced()
+def _four_chip_round_hlo(topo, cfg) -> str:
+    """The DFL round compiled for a 4-chip mesh with the client axis
+    sharded over it (flat gossip kernel, 8 clients, one local step)."""
     ls, b, S = 1, 2, 16
     mesh = Mesh(np.array(topo.devices), ("data",))
     rep = NamedSharding(mesh, P())
@@ -252,7 +250,118 @@ def test_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
                            mix_flat_lowering="flat")
     sharding.set_mesh(mesh)
     try:
-        hlo = _compile(round_fn, *specs)
+        return _compile(round_fn, *specs)
     finally:
         sharding.clear_mesh()
+
+
+def test_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
+    """The DFL round with the flat gossip kernel, client axis sharded over
+    a 4-chip mesh: the kernel must sit inside a shard_map (a Mosaic call
+    cannot be partitioned automatically)."""
+    hlo = _four_chip_round_hlo(topo, get_config("gemma3-1b").reduced())
     assert "tpu_custom_call" in hlo
+
+
+def test_moe_round_compiles_on_four_chips_with_clients_sharded(topo, pallas):
+    """An MoE round on the client-sharded 4-chip mesh keeps dense dispatch:
+    each device runs every expert on its own clients' tokens, with no
+    sort of the (token, expert) pairs across devices and no grouped
+    matmul kernel (a Mosaic call GSPMD cannot partition)."""
+    import re
+
+    hlo = _four_chip_round_hlo(topo, get_config("deepseek-moe-16b").reduced())
+    assert "tpu_custom_call" in hlo           # the gossip kernel
+    assert not re.search(r"moe_gmm|ragged", hlo)
+    assert not re.search(r"= \S+ sort\(", hlo)
+
+
+def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
+    """The deepseek-moe-16b cell's DFL round (4 MoE layers at published
+    widths, 8 clients x 4 local steps x 1 x 256 tokens, the round donated
+    as `Session` builds it) fits one v5e with routed experts: the grouped
+    matmul kernels are Mosaic calls in the program, no dense-dispatch
+    float32 (..., 64, 1408) tensor is left, and arguments plus
+    temporaries fit the chip's 15.75 GB."""
+    import dataclasses
+    import re
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), n_layers=4)
+    ls, b, S = 4, 1, 256
+
+    def loss_fn(bp, lo, micro):
+        (loss, (_, _, load)), per = tf.lm_loss(
+            bp, cfg, micro["tokens"], micro["targets"], lora=lo,
+            per_client=True)
+        return loss, per, {"expert_load": load}
+
+    opt = AdamW(lr=1e-3)
+    key = jax.random.key(0)
+    base = jax.eval_shape(lambda k: tf.init_params(k, cfg), key)
+    lora = jax.eval_shape(
+        lambda k: build_lora_tree(k, tf.init_params(k, cfg), cfg,
+                                  n_clients=M_CLIENTS), key)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                            tree)
+
+    tok = _spec((ls, M_CLIENTS, b, S), jnp.int32, one_chip)
+    round_fn = build_round(loss_fn, opt, local_steps=ls,
+                           mix_flat_lowering="flat", donate=True)
+    compiled = round_fn.lower(
+        on_chip(base), on_chip(lora), on_chip(jax.eval_shape(opt.init, lora)),
+        {"tokens": tok, "targets": tok},
+        _spec((M_CLIENTS, M_CLIENTS), jnp.float32, one_chip),
+        _spec((4,), jnp.float32, one_chip)).compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    for name in ("moe_gmm", "moe_gmm_t"):
+        assert any(re.search(rf"%{name}[.\s]", ln) for ln in calls), name
+    assert not re.search(r"f32\[[\d,]*64,1408\]", hlo)
+    # the kernels read each layer's blocks from the stack: no layer's
+    # expert projection is copied out of it
+    assert not re.search(r"(f32|bf16)\[(1,)?64,(2048,1408|1408,2048)\]",
+                         hlo)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= 15.75e9, mem
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_prefill"])
+def test_moe_serving_steps_read_the_expert_stack(topo, one_chip, pallas,
+                                                 step):
+    """deepseek-moe-16b's serving steps at published widths (4 MoE layers,
+    8 paged slots) take the routed path on one v5e, and the `moe_gmm`
+    kernel reads each layer's blocks from the whole expert stack: no
+    per-layer (64, 2048, 1408) copy of an expert projection is made."""
+    import dataclasses
+    import re
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), n_layers=4)
+    max_len, page_size, chunk = 512, 16, 256
+    base = jax.eval_shape(lambda k: tf.init_params(k, cfg),
+                          jax.random.key(0))
+    cache = tf.init_cache(cfg, N_SLOTS, max_len, specs_only=True,
+                          paging=(1 + N_SLOTS * max_len // page_size,
+                                  page_size))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                            tree)
+
+    scalar = _spec((), jnp.int32, one_chip)
+    if step == "decode":
+        hlo = _compile(lambda p, t, c: tf.decode_step(p, cfg, t, c),
+                       on_chip(base), _spec((N_SLOTS, 1), jnp.int32,
+                                            one_chip), on_chip(cache))
+    else:
+        hlo = _compile(
+            lambda p, t, c, slot, start, limit: tf.chunk_prefill_step(
+                p, cfg, t, c, slot, start, limit),
+            on_chip(base), _spec((1, chunk), jnp.int32, one_chip),
+            on_chip(cache), scalar, scalar, scalar)
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert any(re.search(r"%moe_gmm[.\s]", ln) for ln in calls)
+    assert not re.search(r"(f32|bf16)\[(1,)?64,(2048,1408|1408,2048)\]",
+                         hlo)

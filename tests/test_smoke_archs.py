@@ -4,6 +4,7 @@ variant of each assigned family (<=2 pattern repeats, d_model<=512,
 shapes and no NaNs."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
@@ -88,3 +89,45 @@ def test_reduced_decode_step(arch, key):
         kp = tuple(str(k) for k in p)
         if kp[-1].endswith("'t'") or "t" == getattr(p[-1], "key", ""):
             assert (flat2[kp] == v + 1).all()
+
+
+@pytest.mark.parametrize("arch", ["qwen2-7b", "deepseek-moe-16b"])
+def test_init_params_draws_each_layer_into_the_stack(arch, key):
+    """Scanned layers are drawn straight into their stacked leaves, layer
+    g of pattern position j from its own key, as one layer alone draws."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), n_layers=3)
+    params = tf.init_params(key, cfg)
+    kG = jax.random.split(key, 5)[2]
+    for g in range(cfg.n_groups):
+        one = tf._init_layer(jax.random.fold_in(kG, g), cfg, cfg.pattern[0],
+                             jnp.float32, False)
+        for got, want in zip(jax.tree.leaves(params["groups"][0]),
+                             jax.tree.leaves(one)):
+            assert got.shape[1:] == want.shape
+            np.testing.assert_allclose(got[g], want, rtol=1e-6, atol=1e-7)
+
+
+def test_init_params_compiles_one_program_for_every_seed(monkeypatch):
+    """The stacked layers' draw takes its key as an argument: two seeds
+    lower to the same program, so a persistent compile cache serves
+    every seed after the first."""
+    cfg = get_config("qwen2-7b").reduced()
+    lowered = []
+    real_jit = jax.jit
+
+    def spy(fn, **kw):
+        jitted = real_jit(fn, **kw)
+
+        def call(*args):
+            lowered.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+        return call
+
+    monkeypatch.setattr(jax, "jit", spy)
+    tf.init_params(jax.random.key(1), cfg)
+    n = len(lowered)
+    tf.init_params(jax.random.key(2), cfg)
+    assert n == len(cfg.pattern)
+    assert lowered[n:] == lowered[:n]
