@@ -239,7 +239,11 @@ def _backbone(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
                 loads.append(load)
         return (x, aux), (jnp.stack(loads) if loads else None)
 
-    body = jax.checkpoint(group_body) if remat else group_body
+    # the layer remat recomputes all but MoE's choice of experts and its
+    # routed expert rows (`moe.ROUTED_ROWS`); a model without MoE layers
+    # keeps nothing
+    keep = jax.checkpoint_policies.save_only_these_names(moe_mod.ROUTED_ROWS)
+    body = jax.checkpoint(group_body, policy=keep) if remat else group_body
     has_lora = any(g is not None for g in lo_groups)
     xs = (group_params, lo_groups if has_lora else None,
           jnp.arange(cfg.n_groups))

@@ -282,7 +282,9 @@ def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
     as `Session` builds it) fits one v5e with routed experts: the grouped
     matmul kernels are Mosaic calls in the program, no dense-dispatch
     float32 (..., 64, 1408) tensor is left, and arguments plus
-    temporaries fit the chip's 15.75 GB."""
+    temporaries fit the chip's 15.75 GB. The layer remat keeps the routed
+    rows, so the scanned layer holds three `moe_gmm` calls (gate, up,
+    down) and three `moe_gmm_t` (their backwards): no recomputed forward."""
     import dataclasses
     import re
 
@@ -317,7 +319,8 @@ def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
     hlo = compiled.as_text()
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
     for name in ("moe_gmm", "moe_gmm_t"):
-        assert any(re.search(rf"%{name}[.\s]", ln) for ln in calls), name
+        n = sum(bool(re.search(rf"%{name}[.\s]", ln)) for ln in calls)
+        assert n == 3, (name, n)
     assert not re.search(r"f32\[[\d,]*64,1408\]", hlo)
     # the kernels read each layer's blocks from the stack: no layer's
     # expert projection is copied out of it

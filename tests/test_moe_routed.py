@@ -181,30 +181,45 @@ def test_multi_device_meshes_keep_the_pinned_dense_lowering(monkeypatch):
     assert moe_mod._resolve(None) == "routed"
 
 
-@pytest.mark.parametrize("shared", [0, 2], ids=["no_shared", "shared"])
-def test_routed_moe_lora_gradients_equal_dense(shared, monkeypatch):
-    """Through the whole model, LoRA on wq/wv: the loss, the balance loss
-    and every LoRA gradient agree between routed and dense dispatch."""
-    cfg = _moe_cfg(shared)
-    key = jax.random.key(5)
-    base = tf.init_params(key, cfg)
+def _lora_loss(cfg):
+    """The whole model's loss in its LoRA tree (wq/wv, 3 clients, a
+    nonzero B so that both factors' gradients are exercised), with the
+    balance loss and the expert load."""
+    base = tf.init_params(jax.random.key(5), cfg)
     lora = build_lora_tree(jax.random.key(6), base, cfg, n_clients=3)
-    # a nonzero B so that both factors' gradients are exercised
     lora = jax.tree_util.tree_map_with_path(
         lambda p, t: t + 0.01 if p[-1].key == "b" else t, lora)
     tokens = jax.random.randint(jax.random.key(7), (3, 2, 16), 0,
                                 cfg.vocab_size)
 
+    def loss(lo):
+        (total, (_, aux, load)), _ = tf.lm_loss(
+            base, cfg, tokens, tokens, lora=lo, per_client=True)
+        return total, (aux, load)
+
+    return loss, lora
+
+
+@pytest.mark.parametrize(
+    "shared,backend",
+    [(0, None), (2, None), (0, "pallas_interpret"), (2, "pallas_interpret")],
+    ids=["no_shared", "shared", "no_shared-kernel", "shared-kernel"])
+def test_routed_moe_lora_gradients_equal_dense(shared, backend, monkeypatch):
+    """Through the whole model, LoRA on wq/wv: the loss, the balance loss
+    and every LoRA gradient agree between routed and dense dispatch, on
+    the grouped-matmul kernel's backward too, with the routed rows kept
+    through the layer remat."""
+    cfg = _moe_cfg(shared)
+    loss, lora = _lora_loss(cfg)
+
     def grads(dispatch):
         monkeypatch.setattr(moe_mod, "_resolve", lambda d: dispatch)
-
-        def loss(lo):
-            (total, (_, aux, load)), _ = tf.lm_loss(
-                base, cfg, tokens, tokens, lora=lo, per_client=True)
-            return total, (aux, load)
-
-        with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(loss, has_aux=True)(lora)
+        ops.set_backend(backend)
+        try:
+            with jax.default_matmul_precision("highest"):
+                return jax.value_and_grad(loss, has_aux=True)(lora)
+        finally:
+            ops.set_backend(None)
 
     (l_r, (aux_r, load_r)), g_r = grads("routed")
     (l_d, (aux_d, load_d)), g_d = grads("dense")
@@ -217,6 +232,35 @@ def test_routed_moe_lora_gradients_equal_dense(shared, monkeypatch):
         assert scale > 0
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5 * scale)
+
+
+def _count(jaxpr, primitive: str) -> int:
+    """Equations of ``primitive`` in a jaxpr and every jaxpr nested in it
+    (scan, remat and custom-vjp bodies), each body counted once."""
+    return sum((eqn.primitive.name == primitive)
+               + sum(_count(sub, primitive)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+def test_layer_remat_keeps_the_routed_rows():
+    """The gradient program runs the grouped-matmul kernels six times per
+    scanned MoE layer: gate, up and down forward, and their three
+    backwards to the rows. The layer remat keeps the routed rows
+    (`moe.ROUTED_ROWS`), so it runs no expert product again, and the
+    router's choice, so it chooses no experts again: the kept rows stay
+    in the order the backward reads."""
+    cfg = _moe_cfg(2)
+    assert cfg.n_groups == 2 and [s.ffn for s in cfg.pattern] == ["moe"]
+    loss, lora = _lora_loss(cfg)
+    ops.set_backend("pallas_interpret")
+    try:
+        grad = jax.make_jaxpr(jax.grad(lambda lo: loss(lo)[0]))(lora)
+    finally:
+        ops.set_backend(None)
+    # the scan body holds the pattern's one MoE layer
+    assert _count(grad.jaxpr, "pallas_call") == 6
+    assert _count(grad.jaxpr, "top_k") == 1
 
 
 # ---------------------------------------------------------------------------
