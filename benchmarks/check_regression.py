@@ -11,7 +11,6 @@ Also refuses a ``bench_summary.json`` containing failed benchmarks.
 
 Tracked metrics per artifact (direction-aware):
 
-  BENCH_mixing.json      fused_us per (m, P) mixing point   (lower better)
   BENCH_round_loop.json  session_us_per_round               (lower better)
   BENCH_scenarios.json   us_per_round per scenario          (lower better)
   BENCH_serving.json     tok_s per (n_slots, mode, n_adapters) (higher)
@@ -49,14 +48,6 @@ from typing import Callable, Dict, Tuple
 # metric value + direction: "lower" = regression when current > baseline,
 # "higher" = regression when current < baseline
 Metrics = Dict[str, Tuple[float, str]]
-
-
-def _mixing(doc) -> Metrics:
-    out: Metrics = {}
-    for row in doc.get("mixing", []):
-        key = f"mixing_m{row['m']}_P{row['log2_P']}_fused_us"
-        out[key] = (float(row["fused_us"]), "lower")
-    return out
 
 
 def _round_loop(doc) -> Metrics:
@@ -144,7 +135,6 @@ def _control(doc) -> Metrics:
 
 
 TRACKED: Dict[str, Callable] = {
-    "BENCH_mixing.json": _mixing,
     "BENCH_round_loop.json": _round_loop,
     "BENCH_scenarios.json": _scenarios,
     "BENCH_serving.json": _serving,

@@ -24,14 +24,6 @@ their final losses must agree across every grid AND with each other
 delayed) algorithm whose semantics are process-count independent, so its
 losses must agree across grids but not with dense
 (`overlap_parity_across_grids`).
-
-``sparse_lowering`` probes the flat-vs-per-segment contraction choice of
-the sparse path in-process. The suspicion was that the dense path's
-TPU-only-flat heuristic is stale for sparse comm (the sparse path pays
-the flat buffer anyway, making the fused dot look free) — the probe
-measures the opposite on CPU, so `repro.core.mixing.sparse_use_flat`
-keeps the dense heuristic (flat exactly on TPU meshes), pinned by
-tests/test_comm.py::test_sparse_lowering_auto_pins_flat.
 """
 from __future__ import annotations
 
@@ -79,47 +71,6 @@ def _run_grid(n: int, mode: str, quant: str, rounds: int, tmp: str) -> dict:
             "\n".join(report for _, report in failed))
     with open(out_path) as f:
         return json.load(f)
-
-
-def _probe_sparse_lowering(reps: int = 30) -> dict:
-    """Time the sparse contraction's two lowerings in-process (1-shard
-    degenerate path — the contraction is identical code under shard_map).
-    Evidence for `sparse_use_flat`'s always-flat auto default."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import mixing
-    from repro.core.topology import metropolis_weights, ring_graph
-
-    d, r = CONFIG["model_kw"]["d_model"], 4
-    key = jax.random.PRNGKey(0)
-    lora = {"layers": [
-        {"q": {"a": jax.random.normal(jax.random.fold_in(key, 4 * j),
-                                      (M, d, r)),
-               "b": jax.random.normal(jax.random.fold_in(key, 4 * j + 1),
-                                      (M, r, d))},
-         "v": {"a": jax.random.normal(jax.random.fold_in(key, 4 * j + 2),
-                                      (M, d, r)),
-               "b": jax.random.normal(jax.random.fold_in(key, 4 * j + 3),
-                                      (M, r, d))}}
-        for j in range(CONFIG["model_kw"]["n_layers"])]}
-    W = jnp.asarray(metropolis_weights(ring_graph(M)), jnp.float32)
-
-    out = {}
-    for lowering in ("flat", "per_segment"):
-        fn = jax.jit(lambda W, lo: mixing.mix_tree_sparse(
-            W, lo, 1.0, 1.0, comm_plan=None, flat_lowering=lowering))
-        jax.block_until_ready(fn(W, lora))       # compile
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            res = fn(W, lora)
-        jax.block_until_ready(res)
-        out[f"{lowering}_us"] = round(
-            (time.perf_counter() - t0) / reps * 1e6, 1)
-    out["winner"] = ("flat" if out["flat_us"] <= out["per_segment_us"]
-                     else "per_segment")
-    out["auto_resolves_to"] = ("flat" if mixing.sparse_use_flat("auto")
-                               else "per_segment")
-    return out
 
 
 def run(quick: bool = True, json_path: str | None = None) -> dict:
@@ -195,7 +146,6 @@ def run(quick: bool = True, json_path: str | None = None) -> dict:
         "quant_parity_across_grids": quant_parity,
         "quant_bytes_ratio": round(quant_bytes_ratio, 4),
         "quant_scale_ratio_4p": quant_scale_ratio_4p,
-        "sparse_lowering": _probe_sparse_lowering(),
         "rows": rows,
     }
     print("\n=== multi-process grids (simulated, gloo; static ring) ===")
@@ -206,9 +156,6 @@ def run(quick: bool = True, json_path: str | None = None) -> dict:
               f"{row['rounds_per_s']},{row['scale_vs_1p']},"
               f"{row['comm_bytes_per_round']},"
               f"{row['dense_comm_bytes_per_round']}")
-    sl = result["sparse_lowering"]
-    print(f"sparse lowering probe: flat {sl['flat_us']}us vs per_segment "
-          f"{sl['per_segment_us']}us -> winner {sl['winner']}")
     print(f"loss parity (dense==sparse, all grids): {parity}; "
           f"overlap parity (grids only): {overlap_parity}; "
           f"quant parity (grids only): {quant_parity}")

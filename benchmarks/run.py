@@ -9,7 +9,7 @@ Each module prints its own table and returns a result dict; a final
 ``name,us_per_call,derived`` CSV line per benchmark summarizes wall time
 and the headline derived quantity. ``--json`` additionally writes the
 summary rows as ``[{name, us, headline, failed}]``; the "kernels" bench
-also records the mixing perf trajectory to ``--mixing-json``
+also records its kernel timings to ``--mixing-json``
 (BENCH_mixing.json by default).
 """
 from __future__ import annotations
@@ -51,10 +51,7 @@ def _headline(name: str, result) -> str:
                         for v in result.values())
             return f"adaptive_vs_T1_worstcase={worst:+.4f}"
         if name == "kernels":
-            mix = result.get("mixing") or []
-            best = max((r["speedup"] for r in mix), default=0.0)
-            return (f"n_kernels={len(result) - ('mixing' in result)},"
-                    f"mix_speedup_max={best:.2f}x")
+            return f"n_kernels={len(result)}"
         if name == "roofline":
             ok = sum(1 for v in result.values() if v == "ok")
             return f"combos_ok={ok}"
@@ -94,8 +91,8 @@ def main() -> None:
     ap.add_argument("--json", default="",
                     help="write per-benchmark summary rows to this path")
     ap.add_argument("--mixing-json", default="BENCH_mixing.json",
-                    help="where the kernels bench records the mixing "
-                         "perf trajectory ('' disables)")
+                    help="where the kernels bench records its "
+                         "timings ('' disables)")
     ap.add_argument("--round-loop-json", default="BENCH_round_loop.json",
                     help="where the round_loop bench records the Session "
                          "overhead trajectory ('' disables)")

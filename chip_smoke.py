@@ -330,8 +330,7 @@ def phase_sharded(config: DFLConfig) -> None:
     W = np.asarray(cluster.topo_schedule.next_w(0), np.float32)
 
     def mix(w, lo):
-        return mixing.mix_tree_planned(w, lo, 1.0, 0.5,
-                                       flat_lowering="flat")
+        return mixing.mix_tree_planned(w, lo, 1.0, 0.5)
 
     want = jax.jit(mix)(jnp.asarray(W), jax.tree.map(jnp.asarray, tree))
     w_rep = multihost.replicate(mesh, W)

@@ -10,11 +10,12 @@ the grid (`repro.dist.multihost`):
   * each process owns a contiguous shard of the client axis (m must divide
     over the grid's devices); local training is shard-local,
   * under ``mix_comm="dense"`` the gossip mix runs with ``mix_gather``
-    resolved on: one all-gather of the stacked LoRA state per round (the
-    paper's communication step, lowered to a cross-process collective)
-    followed by a replicated W_t contraction — bitwise equal to the
-    single-process round. Under ``mix_comm="sparse"/"sparse_overlap"``
-    the round instead runs the `repro.dist.comm.CommPlan` halo exchange:
+    on (it is on exactly when the grid has >1 process): one all-gather
+    of the stacked LoRA state per round (the paper's communication step,
+    lowered to a cross-process collective) followed by a replicated W_t
+    contraction — bitwise equal to the single-process round. Under
+    ``mix_comm="sparse"/"sparse_overlap"`` the round instead runs the
+    `repro.dist.comm.CommPlan` halo exchange:
     one small all-gather of only the topology-coupled rows ("sparse" is
     still bitwise equal; "sparse_overlap" delays neighbor terms one
     round so the exchange overlaps local compute),
@@ -65,9 +66,9 @@ class ClusterSession(Session):
     """Multi-process DFL session: one process = one shard of the clients.
 
     Accepts every `Session` argument. Requires ``config.n_clients`` to be
-    divisible by the grid's total device count. ``config.mix_gather`` is
-    resolved per `repro.api.session._resolve_mix_gather` — "auto" turns
-    the pre-mix all-gather on exactly when the grid has >1 process.
+    divisible by the grid's total device count. The round's pre-mix
+    all-gather (`repro.api.session._resolve_mix_gather`) is on exactly
+    when the grid has >1 process.
     """
 
     def __init__(self, config, **kw):

@@ -3,10 +3,10 @@
 One frozen dataclass captures everything the paper's protocol needs:
 model/task, federation geometry (clients, graph family + topology_kw,
 communication scenario + scenario_kw, p), method + switching interval,
-optimization (rounds, local steps, lr, batch), engine knobs (mixing
-lowering, donation), and seeds. A `Session` (repro.api.session)
-turns a config into a running experiment; `cache_key()` is a stable JSON
-hash used by the benchmark results cache.
+optimization (rounds, local steps, lr, batch), engine knobs (gossip
+exchange and its compression, donation), and seeds. A `Session`
+(repro.api.session) turns a config into a running experiment;
+`cache_key()` is a stable JSON hash used by the benchmark results cache.
 
 Seed conventions (all derivable from `seed` unless overridden):
   base params   <- jax.random.key(init_seed)        (init_seed = seed)
@@ -28,20 +28,16 @@ from typing import Mapping, Optional, Union
 
 from repro.control.config import ControlConfig
 from repro.core.alternating import METHODS
+from repro.core.mixing import MIX_COMM_MODES, MIX_QUANT_MODES
 from repro.core.topology import GRAPH_FAMILIES
 from repro.data.partition import PARTITIONERS
 from repro.scenarios.library import SCENARIOS
 
 CLASSIFIER_TASKS = ("sst2", "qqp", "qnli", "mnli")
 TOPOLOGIES = GRAPH_FAMILIES
-MIX_IMPLS = ("planned", "per_leaf", "concat")
-FLAT_LOWERINGS = ("auto", "flat", "per_segment")
-MIX_GATHER_MODES = ("auto", "on", "off")
-MIX_COMM_MODES = ("dense", "sparse", "sparse_overlap")
-MIX_QUANT_MODES = ("off", "int8", "fp8")
 DATA_SOURCES = ("synthetic", "shards")
 
-_KEY_VERSION = 8   # bump when semantics of any field change
+_KEY_VERSION = 9   # bump when semantics of any field change
 
 # legacy flat-knob defaults (pre-v8 configs; see DFLConfig.control)
 _LEGACY_ADAPTIVE = {"adaptive_T": False, "adaptive_c": 0.35,
@@ -90,12 +86,6 @@ class DFLConfig:
     lr: float = 1e-3
 
     # -- engine -------------------------------------------------------------
-    mix_impl: str = "planned"
-    mix_flat_lowering: str = "auto"   # auto = flat on TPU, per-segment off
-    mix_gather: str = "auto"     # dense mode: all-gather clients before
-                                 # mixing: auto = on iff multi-process
-                                 # (bitwise cluster parity), "on"/"off"
-                                 # pin it (ignored by sparse modes)
     mix_comm: str = "dense"      # gossip comm lowering: "dense" |
                                  # "sparse" (topology-support exchange,
                                  # bitwise equal) | "sparse_overlap"
@@ -197,20 +187,9 @@ class DFLConfig:
         check(not (self.scenario in ("gossip", "static")
                    and self.scenario_kw),
               f"scenario {self.scenario!r} takes no scenario_kw")
-        check(self.mix_impl in MIX_IMPLS,
-              f"unknown mix_impl {self.mix_impl!r}; known: {MIX_IMPLS}")
-        check(self.mix_flat_lowering in FLAT_LOWERINGS,
-              f"unknown mix_flat_lowering {self.mix_flat_lowering!r}; "
-              f"known: {FLAT_LOWERINGS}")
-        check(self.mix_gather in MIX_GATHER_MODES,
-              f"unknown mix_gather {self.mix_gather!r}; "
-              f"known: {MIX_GATHER_MODES}")
         check(self.mix_comm in MIX_COMM_MODES,
               f"unknown mix_comm {self.mix_comm!r}; "
               f"known: {MIX_COMM_MODES}")
-        check(self.mix_comm == "dense" or self.mix_impl == "planned",
-              f"mix_comm {self.mix_comm!r} lowers through the MixPlan "
-              f"flat layout; it requires mix_impl='planned'")
         check(self.mix_quant in MIX_QUANT_MODES,
               f"unknown mix_quant {self.mix_quant!r}; "
               f"known: {MIX_QUANT_MODES}")

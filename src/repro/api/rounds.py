@@ -1,52 +1,9 @@
-"""API-level constructor for the compiled DFL round.
+"""API-level name of the compiled DFL round's constructor.
 
-`build_round` is the one place the experiment layer (Session, launchers,
-dry-run spec builders) obtains a round function; everything above
-`repro.core` routes through it so engine knobs (mixing lowering, buffer
-donation) are applied uniformly. The low-level `repro.core.make_dfl_round`
-remains exported for library users who wire loops themselves.
+The experiment layer (Session, launchers, dry-run spec builders) obtains
+its round function as `build_round`; it is `repro.core.make_dfl_round`
+itself, so the two take the same arguments.
 """
-from __future__ import annotations
-
-from typing import Callable, Optional
-
 from repro.core.fedtrain import make_dfl_round
-from repro.optim.adamw import AdamW
 
-
-def build_round(loss_fn: Callable, optimizer: AdamW, *,
-                local_steps: int = 1,
-                mix_impl: str = "planned",
-                mix_flat_lowering: Optional[str] = None,
-                mix_gather: bool = False,
-                mix_comm: str = "dense",
-                mix_quant: str = "off",
-                comm_plan=None,
-                donate: bool = False):
-    """Build round_fn(base, lora, opt_state, batch, W, masks).
-
-    mix_flat_lowering ("auto" | "flat" | "per_segment") pins the planned
-    path's fused-buffer lowering for this round function; None defers to
-    the process default (repro.core.mixing.set_flat_lowering).
-    mix_gather pins the dense cluster communication step: all-gather the
-    client axis before the mixing contraction (bitwise-parity lowering
-    for multi-process runs; no-op without a bound mesh).
-    mix_comm ("dense" | "sparse" | "sparse_overlap") selects the gossip
-    communication lowering; the sparse modes exchange only the
-    topology-coupled rows described by ``comm_plan`` (a
-    `repro.dist.comm.CommPlan`), and "sparse_overlap" delays the
-    off-diagonal mixing terms by one round so the exchange overlaps with
-    local compute.
-    mix_quant ("off" | "int8" | "fp8") compresses the sparse halo
-    exchange with per-client error feedback; quant round functions take
-    an extra ``ef`` buffer and return ``ef_new`` (see
-    `repro.core.fedtrain.make_dfl_round`).
-    """
-    return make_dfl_round(loss_fn, optimizer, local_steps=local_steps,
-                          mix_impl=mix_impl,
-                          mix_flat_lowering=mix_flat_lowering,
-                          mix_gather=mix_gather,
-                          mix_comm=mix_comm,
-                          mix_quant=mix_quant,
-                          comm_plan=comm_plan,
-                          donate=donate)
+build_round = make_dfl_round

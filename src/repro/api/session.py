@@ -129,15 +129,11 @@ class _Built:
 _BUILD_CACHE: dict = {}
 
 
-def _resolve_mix_gather(mode: str) -> bool:
-    """"auto" turns the pre-mix client all-gather on exactly when the run
-    spans processes (repro.dist.multihost) — single-process rounds keep
-    the unconstrained lowering, cluster rounds pin the bitwise-parity
+def _resolve_mix_gather() -> bool:
+    """The pre-mix client all-gather is on exactly when the run spans
+    processes (repro.dist.multihost): single-process rounds keep the
+    unconstrained lowering, cluster rounds pin the bitwise-parity
     communication step."""
-    if mode == "on":
-        return True
-    if mode == "off":
-        return False
     return jax.process_count() > 1
 
 
@@ -157,8 +153,7 @@ def _comm_plan_for(cfg: DFLConfig) -> Optional[CommPlan]:
 def _build_key(cfg: DFLConfig, comm_plan: Optional[CommPlan] = None):
     return (cfg.model, cfg.reduced, cfg.model_kw, cfg.task,
             cfg.feature_shift, cfg.n_clients, cfg.lr, cfg.local_steps,
-            cfg.mix_impl, cfg.mix_flat_lowering,
-            _resolve_mix_gather(cfg.mix_gather), cfg.donate, cfg.init_seed,
+            _resolve_mix_gather(), cfg.donate, cfg.init_seed,
             cfg.mix_comm, cfg.mix_quant,
             cfg.data_source, cfg.data_path,
             comm_plan.signature() if comm_plan is not None else None)
@@ -225,9 +220,7 @@ def _build(cfg: DFLConfig, model_cfg, loss_fn) -> _Built:
     lora0 = build_lora_tree(lora_key, base, mc, n_clients=cfg.n_clients)
     opt = AdamW(lr=cfg.lr)
     round_fn = build_round(loss_fn, opt, local_steps=cfg.local_steps,
-                           mix_impl=cfg.mix_impl,
-                           mix_flat_lowering=cfg.mix_flat_lowering,
-                           mix_gather=_resolve_mix_gather(cfg.mix_gather),
+                           mix_gather=_resolve_mix_gather(),
                            mix_comm=cfg.mix_comm,
                            mix_quant=cfg.mix_quant,
                            comm_plan=comm_plan,

@@ -8,11 +8,9 @@ from repro.core.fedtrain import make_dfl_round, make_microbatches
 from repro.core.lora import (build_lora_tree, client_mean, client_slice,
                              lora_specs, merge_lora, param_count,
                              shard_lora_tree, target_names)
-from repro.core.mixing import (MixPlan, build_mix_plan, flat_lowering_mode,
-                               get_mix_plan, mix_leaf, mix_tree,
-                               mix_tree_concat, mix_tree_planned,
-                               plan_builds, set_flat_lowering,
-                               use_flat_lowering)
+from repro.core.mixing import (MixPlan, build_mix_plan, get_mix_plan,
+                               mix_leaf, mix_tree, mix_tree_planned,
+                               plan_builds)
 from repro.core.topology import (Topology, make_topology,
                                  optimal_switching_interval,
                                  optimal_switching_interval_edge_activation,
@@ -24,9 +22,8 @@ __all__ = [
     "make_dfl_round", "make_microbatches",
     "build_lora_tree", "client_mean", "client_slice", "lora_specs",
     "merge_lora", "param_count", "shard_lora_tree", "target_names",
-    "MixPlan", "build_mix_plan", "flat_lowering_mode", "get_mix_plan",
-    "mix_leaf", "mix_tree", "mix_tree_concat", "mix_tree_planned",
-    "plan_builds", "set_flat_lowering", "use_flat_lowering",
+    "MixPlan", "build_mix_plan", "get_mix_plan",
+    "mix_leaf", "mix_tree", "mix_tree_planned", "plan_builds",
     "Topology", "make_topology", "optimal_switching_interval",
     "optimal_switching_interval_edge_activation", "sample_mixing_matrix",
     "lambda2",

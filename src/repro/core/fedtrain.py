@@ -14,8 +14,7 @@ AdamW's mu/sqrt(nu) normalization (scale invariance, eps aside).
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -34,19 +33,8 @@ def _ab_mask(masks):
     return fn
 
 
-_MIX_IMPLS = {
-    "planned": mixing.mix_tree_planned,    # default: plan-cached fused path
-    "per_leaf": mixing.mix_tree,           # the oracle
-    "concat": mixing.mix_tree_concat,      # legacy fused (no plan cache)
-}
-
-MIX_COMM_MODES = ("dense", "sparse", "sparse_overlap")
-
-
 def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                    local_steps: int = 1,
-                   mix_impl: str = "planned",
-                   mix_flat_lowering: Optional[str] = None,
                    mix_gather: bool = False,
                    mix_comm: str = "dense",
                    mix_quant: str = "off",
@@ -68,13 +56,8 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
       -> (lora, opt_state, metrics)
     ``batch`` leaves have a leading (local_steps, ...) axis.
 
-    mix_impl "planned" (default) mixes through a cached MixPlan: one fused
-    gossip_mix_seg sweep, one collective under GSPMD. "per_leaf" is the
-    bit-for-bit oracle (at equal masks); "concat" the legacy fused variant.
-    ``mix_flat_lowering`` ("auto"/"flat"/"per_segment", None = process
-    default) pins the planned path's buffer lowering — "auto" gates the
-    flat (m, P) buffer to TPU backends (SPMD full-remat warning on the
-    chunk reshape under GSPMD; per-segment dots win off-TPU).
+    The dense mix is `mixing.mix_tree_planned`: one gossip_mix_seg call
+    on the cached MixPlan's flat buffer.
     With ``mix_gather`` the stacked LoRA state is constrained fully
     replicated BEFORE the mixing contraction: under a cluster mesh
     (repro.dist.multihost) this pins the communication step to one
@@ -102,22 +85,15 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
     buffers donated (in-place round at production scale) — callers must
     then treat the passed-in trees as consumed.
     """
-    if mix_comm not in MIX_COMM_MODES:
+    if mix_comm not in mixing.MIX_COMM_MODES:
         raise ValueError(f"unknown mix_comm {mix_comm!r}; "
-                         f"known: {MIX_COMM_MODES}")
-    if mix_comm != "dense" and mix_impl != "planned":
-        raise ValueError("sparse mix_comm lowers through the MixPlan flat "
-                         "layout; it requires mix_impl='planned'")
+                         f"known: {mixing.MIX_COMM_MODES}")
     if mix_quant not in mixing.MIX_QUANT_MODES:
         raise ValueError(f"unknown mix_quant {mix_quant!r}; "
                          f"known: {mixing.MIX_QUANT_MODES}")
     if mix_quant != "off" and mix_comm == "dense":
         raise ValueError("mix_quant compresses the sparse halo exchange; "
                          "it requires mix_comm='sparse' or 'sparse_overlap'")
-    mix = _MIX_IMPLS[mix_impl]
-    if mix_impl == "planned":
-        mix = partial(mixing.mix_tree_planned,
-                      flat_lowering=mix_flat_lowering)
 
     def _local_phase(base_params, lora, opt_state, batch, masks):
         """The local-steps scan — shared between the plain and the
@@ -166,7 +142,8 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
             if mix_comm == "dense":
                 if mix_gather:
                     lora_new = gather_clients(lora_new)
-                lora_new = mix(W, lora_new, masks[2], masks[3])
+                lora_new = mixing.mix_tree_planned(W, lora_new, masks[2],
+                                                   masks[3])
             else:
                 # overlap feeds the ROUND-INPUT state to the off-diagonal
                 # terms: its exchange is independent of the local-steps
@@ -174,8 +151,7 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
                 lora_new = mixing.mix_tree_sparse(
                     W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
                     lora_prev=(lora if mix_comm == "sparse_overlap"
-                               else None),
-                    flat_lowering=mix_flat_lowering)
+                               else None))
             lora_new = shard_lora_tree(lora_new)
         metrics = _metrics(losses, per_client, counters)
         return lora_new, opt_new, metrics
@@ -189,7 +165,7 @@ def make_dfl_round(loss_fn: Callable, optimizer: AdamW, *,
             lora_new, ef_new = mixing.mix_tree_sparse(
                 W, lora_new, masks[2], masks[3], comm_plan=comm_plan,
                 lora_prev=(lora if mix_comm == "sparse_overlap" else None),
-                flat_lowering=mix_flat_lowering, quant=mix_quant, ef=ef)
+                quant=mix_quant, ef=ef)
             lora_new = shard_lora_tree(lora_new)
         metrics = _metrics(losses, per_client, counters)
         return lora_new, opt_new, metrics, ef_new

@@ -1,29 +1,24 @@
 """Gossip mixing of stacked client LoRA states (Algorithm 1, lines 7-9).
 
 Every LoRA leaf carries the client axis at position -3 (see core.lora), so
-mixing is uniformly  x'_i = Σ_j (W_t)_ij x_j  — an einsum contracting that
-axis. Under the production mesh the client axis is sharded over
-("pod","data"), so this einsum *is* the paper's communication step, lowered
-by GSPMD to collectives over the client axis.
+mixing is uniformly  x'_i = Σ_j (W_t)_ij x_j  — a contraction over that
+axis, which under a client-sharded mesh *is* the paper's communication
+step.
 
 ``mix_masks`` lets one compiled step express all four paper methods: a leaf
 is mixed when its mask is 1, left untouched when 0 (traced scalars, so the
 method/phase never triggers recompilation).
 
-Four lowerings, equal numerics (bit-for-bit at binary masks):
-  mix_tree         — per-leaf einsum + blend (the oracle; one collective
-                     per leaf under GSPMD).
-  mix_tree_concat  — legacy fused variant: re-derives the flatten layout
-                     from tree paths on every call.
-  mix_tree_planned — the default fast path: a MixPlan (built once per
-                     treedef/shape signature, cached) precomputes per-leaf
-                     offsets, the padded (m, P) layout aligned to the
-                     gossip_mix kernel's bp stripe, and the a/b column
-                     segment indicator, so the per-round work is one
-                     gather into the flat buffer, ONE gossip_mix_seg call
-                     (one collective under GSPMD, unequal masks folded
-                     into the per-segment W_eff), and one unflatten — no
-                     per-round Python tree traversal.
+  mix_tree         — per-leaf einsum + blend: the oracle the tests hold the
+                     other two to.
+  mix_tree_planned — the dense round's mix: a MixPlan (built once per
+                     treedef/shape signature, cached) fixes every leaf's
+                     columns in one flat (m, padded) buffer, padded to the
+                     gossip_mix kernel's bp stripe, with the a/b column
+                     segment indicator. Per round: one gather into the
+                     flat buffer, ONE ``ops.gossip_mix_seg`` call (unequal
+                     masks folded into the per-column W_eff; column-sharded
+                     across a multi-device mesh) and one unflatten.
   mix_tree_sparse  — the cluster communication lowering
                      (`mix_comm="sparse"/"sparse_overlap"`): the same
                      MixPlan flat layout, but the cross-process exchange
@@ -48,9 +43,10 @@ diagonal contribution stays full precision. A per-client error-feedback
 accumulator (EF21-style) carries the quantization residual into the next
 round's payload, e_j' = (x_j + e_j) − Q(x_j + e_j), so the compression
 noise stays summable and the consensus contraction survives (asserted
-against the Lemma A.10 budget in the conformance tier). Quantization is
-per-row and the degenerate path quantizes ALL off-diagonal sources, so
-single- and multi-process runs still agree bit-for-bit.
+against the Lemma A.10 budget in the conformance tier). The dequantize is
+fused into the ``ops.gossip_mix_quant`` sweep. Quantization is per-row and
+the degenerate path quantizes ALL off-diagonal sources, so single- and
+multi-process runs still agree bit-for-bit.
 """
 from __future__ import annotations
 
@@ -67,6 +63,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as _sharding
 from repro.kernels import ops
+
+# the gossip exchange's lowering: the dense all-clients contraction, the
+# topology-support halo, or that halo one round delayed
+MIX_COMM_MODES = ("dense", "sparse", "sparse_overlap")
 
 
 def mix_leaf(W: jax.Array, leaf: jax.Array) -> jax.Array:
@@ -101,38 +101,8 @@ def mix_tree(W: jax.Array, lora, mask_a: jax.Array, mask_b: jax.Array):
     return jax.tree_util.tree_map_with_path(one, lora)
 
 
-def mix_tree_concat(W: jax.Array, lora, mask_a: jax.Array, mask_b: jax.Array):
-    """Beyond-paper lowering variant (§Perf): flatten all leaves into one
-    (m, P) buffer, mix with a single matmul (one collective), then unflatten.
-    Numerically identical to mix_tree when masks are equal; with unequal
-    masks it falls back to per-leaf masking after the fused mix."""
-    leaves, treedef = jax.tree_util.tree_flatten(lora)
-    m = leaves[0].shape[-3]
-
-    def to2d(x):
-        # (..., m, d0, d1) -> (m, prod(lead)*d0*d1)
-        x = jnp.moveaxis(x, -3, 0)
-        return x.reshape(m, -1)
-
-    flat = jnp.concatenate([to2d(x) for x in leaves], axis=1)
-    mixed_flat = W.astype(flat.dtype) @ flat
-
-    out, off = [], 0
-    paths = jax.tree_util.tree_flatten_with_path(lora)[0]
-    for (path, leaf) in paths:
-        n = leaf.size // m
-        chunk = mixed_flat[:, off:off + n]
-        off += n
-        lead = leaf.shape[:-3]
-        restored = chunk.reshape(m, *lead, *leaf.shape[-2:])
-        restored = jnp.moveaxis(restored, 0, len(lead))
-        mask = mask_a if _leaf_mask_name(path) == "a" else mask_b
-        out.append((mask * restored + (1.0 - mask) * leaf).astype(leaf.dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 # ===========================================================================
-# Planned fused mixing (the default fast path)
+# Planned fused mixing (the dense round's mix)
 # ===========================================================================
 
 _KERNEL_BP = 512    # gossip_mix stripe width the flat buffer is padded to
@@ -232,51 +202,6 @@ def get_mix_plan(lora, *, bp: int = _KERNEL_BP) -> MixPlan:
     return plan
 
 
-_FLAT_LOWERING_MODES = ("auto", "flat", "per_segment")
-_flat_lowering_mode = "auto"
-
-
-def set_flat_lowering(mode: str) -> str:
-    """Set the process-default flat-lowering mode; returns the previous.
-
-    "flat"        — always flatten into the single (m, P) gossip_mix buffer
-    "per_segment" — always keep the plan's per-slot W_eff dots
-    "auto"        — flat on TPU backends only (default). GSPMD emits an
-                    involuntary-full-remat warning on the chunk reshape of
-                    the flat buffer (ROADMAP open item), and off-TPU the
-                    two full-buffer copies dominate the cache-resident
-                    per-slot dots (~4x, BENCH_mixing.json) — so the flat
-                    path is gated to TPU meshes by default.
-    """
-    global _flat_lowering_mode
-    if mode not in _FLAT_LOWERING_MODES:
-        raise ValueError(f"unknown flat-lowering mode {mode!r}; "
-                         f"known: {_FLAT_LOWERING_MODES}")
-    prev, _flat_lowering_mode = _flat_lowering_mode, mode
-    return prev
-
-
-def flat_lowering_mode() -> str:
-    return _flat_lowering_mode
-
-
-def use_flat_lowering(mode: Optional[str] = None) -> bool:
-    """Resolve a mode (None -> the process default) to a concrete choice."""
-    mode = mode if mode is not None else _flat_lowering_mode
-    if mode == "flat":
-        return True
-    if mode == "per_segment":
-        return False
-    if mode != "auto":
-        raise ValueError(f"unknown flat-lowering mode {mode!r}; "
-                         f"known: {_FLAT_LOWERING_MODES}")
-    return jax.default_backend() == "tpu"
-
-
-# backwards-compat alias (benchmarks/tests of earlier PRs)
-_use_flat_lowering = use_flat_lowering
-
-
 def _column_sharded_mix(w, flat, seg):
     """``ops.gossip_mix_seg`` on the flat (m, P) buffer, under the bound
     mesh when it spans several devices.
@@ -302,103 +227,56 @@ def _column_sharded_mix(w, flat, seg):
     return mixed[:, :cols]
 
 
+def _flat_buffer(leaves, m: int, pad: int = 0):
+    """(m, cols + pad) flat view of the stacked tree in plan layout, with
+    ``pad`` zero columns at the end. The sparse path passes no padding:
+    the halo exchange should not ship padding bytes."""
+    parts = [jnp.moveaxis(x, -3, 0).reshape(m, -1) for x in leaves]
+    if pad:
+        parts.append(jnp.zeros((m, pad), parts[0].dtype))
+    return jnp.concatenate(parts, axis=1)
+
+
+def _unflatten(plan: MixPlan, mixed, leaves):
+    """The tree of ``mixed``'s (m, ≥cols) flat plan layout, in the dtypes
+    of ``leaves``."""
+    out = []
+    for slot, leaf in zip(plan.slots, leaves):
+        chunk = mixed[:, slot.offset:slot.offset + slot.cols]
+        restored = chunk.reshape(plan.m, *slot.lead, *slot.tail)
+        restored = jnp.moveaxis(restored, 0, len(slot.lead))
+        out.append(restored.astype(leaf.dtype))
+    return jax.tree_util.tree_unflatten(plan.treedef, out)
+
+
 def mix_tree_planned(W: jax.Array, lora, mask_a, mask_b, *,
-                     plan: Optional[MixPlan] = None,
-                     flat_lowering: Optional[str] = None):
-    """Plan-cached fused mixing (the default fast path).
+                     plan: Optional[MixPlan] = None):
+    """Plan-cached fused mixing: the dense round's mix.
 
-    Masks are folded into per-segment effective mixing matrices
-    W_eff = mask·W + (1−mask)·I — the blend never touches the (m, P)
-    payload as a separate pass. With the flat lowering the whole tree is
-    mixed by ONE gossip_mix_seg kernel call on the plan's padded flat
-    layout (column-sharded across a multi-device mesh — see
-    `_column_sharded_mix`); otherwise each slot is a single dot with its
-    segment's W_eff. Numerically equal to mix_tree for all masks and
-    bit-for-bit at equal masks (W_eff reduces to W exactly).
-
-    ``flat_lowering`` pins the buffer lowering for this call ("flat" /
-    "per_segment" / "auto"); None defers to ``set_flat_lowering``'s
-    process default (auto: flat on TPU only).
+    The whole tree is mixed by ONE gossip_mix_seg call on the plan's
+    padded flat layout (column-sharded across a multi-device mesh — see
+    `_column_sharded_mix`). Masks fold into the per-column effective
+    mixing matrix W_eff = seg·W + (1−seg)·I, so the blend never touches
+    the (m, P) payload as a separate pass. Numerically equal to mix_tree
+    for all masks.
     """
     plan = plan if plan is not None else get_mix_plan(lora)
     leaves = jax.tree_util.tree_leaves(lora)
-    m = plan.m
-
-    if use_flat_lowering(flat_lowering):
-        parts = [jnp.moveaxis(x, -3, 0).reshape(m, -1) for x in leaves]
-        if plan.padded > plan.cols:
-            parts.append(jnp.zeros((m, plan.padded - plan.cols),
-                                   parts[0].dtype))
-        flat = jnp.concatenate(parts, axis=1)
-        seg = plan.segment_mask(mask_a, mask_b).astype(flat.dtype)
-        mixed = _column_sharded_mix(W.astype(flat.dtype), flat, seg)
-        out = []
-        for slot, leaf in zip(plan.slots, leaves):
-            chunk = mixed[:, slot.offset:slot.offset + slot.cols]
-            restored = chunk.reshape(m, *slot.lead, *slot.tail)
-            restored = jnp.moveaxis(restored, 0, len(slot.lead))
-            out.append(restored.astype(leaf.dtype))
-        return jax.tree_util.tree_unflatten(plan.treedef, out)
-
-    # cache-local lowering: two (m, m) W_eff folds per round, then one
-    # blend-free dot per slot (is_a is plan-static — no path inspection)
-    eye = jnp.eye(m, dtype=W.dtype)
-    w_a = mask_a * W + (1.0 - mask_a) * eye
-    w_b = mask_b * W + (1.0 - mask_b) * eye
-    out = [
-        jnp.einsum("ij,...jdr->...idr",
-                   (w_a if slot.is_a else w_b).astype(leaf.dtype),
-                   leaf).astype(leaf.dtype)
-        for slot, leaf in zip(plan.slots, leaves)
-    ]
-    return jax.tree_util.tree_unflatten(plan.treedef, out)
+    flat = _flat_buffer(leaves, plan.m, plan.padded - plan.cols)
+    seg = plan.segment_mask(mask_a, mask_b).astype(flat.dtype)
+    mixed = _column_sharded_mix(W.astype(flat.dtype), flat, seg)
+    return _unflatten(plan, mixed, leaves)
 
 
 # ===========================================================================
 # Sparse (neighbor-only) gossip lowering — repro.dist.comm.CommPlan
 # ===========================================================================
 
-def sparse_use_flat(mode: Optional[str] = None) -> bool:
-    """Resolve the contraction lowering for the SPARSE comm path.
-
-    Explicit "flat"/"per_segment" pin it; "auto"/None follow the dense
-    planned path's backend heuristic (flat on TPU meshes, per-segment
-    dots elsewhere). The plausible counter-argument — the sparse path
-    assembles the flat (m, cols) buffer anyway for the halo exchange, so
-    one fused (rows, m) @ (m, cols) dot should win everywhere — was
-    MEASURED FALSE on CPU: the per-column seg blend of the flat
-    contraction costs more than it saves over per-slot dots with scalar
-    blends (~110us vs ~70us at the bench shape,
-    BENCH_multihost.json's `sparse_lowering` probe), and inside a real
-    distributed round either choice is <0.1% of round wall time. Pinned
-    by tests/test_comm.py::test_sparse_lowering_auto_pins_flat (flat
-    exactly where the fused gossip kernel lives — TPU).
-    """
-    mode = mode if mode is not None else flat_lowering_mode()
-    if mode == "flat":
-        return True
-    if mode == "per_segment":
-        return False
-    if mode != "auto":
-        raise ValueError(f"unknown flat-lowering mode {mode!r}; "
-                         f"known: {_FLAT_LOWERING_MODES}")
-    return jax.default_backend() == "tpu"
-
-
-def _flat_buffer(leaves, m: int):
-    """(m, cols) unpadded flat view of the stacked tree (plan layout).
-    The sparse path skips the bp padding — it contracts with plain dots,
-    not the stripe-aligned gossip_mix kernel, and the halo exchange
-    should not ship padding bytes."""
-    return jnp.concatenate(
-        [jnp.moveaxis(x, -3, 0).reshape(m, -1) for x in leaves], axis=1)
-
-
 # ---------------------------------------------------------------------------
 # compressed gossip: per-row quantization + error feedback
 # ---------------------------------------------------------------------------
 
-MIX_QUANT_MODES = ("off", "int8", "fp8")
+MIX_QUANT_MODES = ("off", "int8", "fp8")   # compression of the sparse halo
 
 
 def _quant_spec(quant: str):
@@ -452,7 +330,7 @@ def _split_diag(w_rows, row0):
 
 
 def _sparse_contract(w_rows, x_rows, z, mask_a, mask_b, plan: MixPlan,
-                     use_flat: bool, w_diag=None):
+                     w_diag=None):
     """Blend-mixed rows from the exchanged source buffer.
 
     w_rows: (r, m) mixing rows (diagonal zeroed when w_diag is given);
@@ -461,26 +339,16 @@ def _sparse_contract(w_rows, x_rows, z, mask_a, mask_b, plan: MixPlan,
     support are zero and meet exact-zero W entries). w_diag: (r, 1)
     diagonal coefficients applied to the FRESH rows (overlap mode).
     """
-    if use_flat:
-        mixed = w_rows @ z
-        if w_diag is not None:
-            mixed = w_diag * x_rows + mixed
-        seg = plan.segment_mask(mask_a, mask_b)[:, :plan.cols]
-        seg = seg.astype(x_rows.dtype)
-        return seg * mixed + (1.0 - seg) * x_rows
-    outs = []
-    for slot in plan.slots:
-        sl = slice(slot.offset, slot.offset + slot.cols)
-        mask = mask_a if slot.is_a else mask_b
-        mixed = w_rows @ z[:, sl]
-        if w_diag is not None:
-            mixed = w_diag * x_rows[:, sl] + mixed
-        outs.append(mask * mixed + (1.0 - mask) * x_rows[:, sl])
-    return jnp.concatenate(outs, axis=1)
+    mixed = w_rows @ z
+    if w_diag is not None:
+        mixed = w_diag * x_rows + mixed
+    seg = plan.segment_mask(mask_a, mask_b)[:, :plan.cols]
+    seg = seg.astype(x_rows.dtype)
+    return seg * mixed + (1.0 - seg) * x_rows
 
 
 def _sparse_contract_quant(w_off, x_rows, zq, zscale, mask_a, mask_b,
-                           plan: MixPlan, use_flat: bool, w_diag):
+                           plan: MixPlan, w_diag):
     """Blend-mixed rows from a QUANTIZED source buffer.
 
     w_off: (r, m) mixing rows with the diagonal zeroed; x_rows: (r, cols)
@@ -488,22 +356,16 @@ def _sparse_contract_quant(w_off, x_rows, zq, zscale, mask_a, mask_b,
     quantized source rows + per-row scales (rows outside the support are
     zero and meet exact-zero W entries); w_diag: (r, 1) diagonal
     coefficients applied to the FRESH rows — the local contribution never
-    pays quantization noise. The flat lowering fuses the dequantize into
-    the `gossip_mix_quant` kernel sweep; per-segment dequantizes once and
-    reuses the per-slot dots.
+    pays quantization noise. The dequantize is fused into the
+    `gossip_mix_quant` kernel sweep.
     """
-    if use_flat:
-        seg = plan.segment_mask(mask_a, mask_b)[:, :plan.cols]
-        seg = jnp.asarray(seg).astype(x_rows.dtype)
-        return ops.gossip_mix_quant(w_off, zq, zscale, x_rows, w_diag, seg)
-    z = dequantize_rows(zq, zscale).astype(x_rows.dtype)
-    return _sparse_contract(w_off, x_rows, z, mask_a, mask_b, plan,
-                            use_flat=False, w_diag=w_diag)
+    seg = plan.segment_mask(mask_a, mask_b)[:, :plan.cols]
+    seg = jnp.asarray(seg).astype(x_rows.dtype)
+    return ops.gossip_mix_quant(w_off, zq, zscale, x_rows, w_diag, seg)
 
 
 def mix_tree_sparse(W: jax.Array, lora, mask_a, mask_b, *, comm_plan,
                     lora_prev=None, plan: Optional[MixPlan] = None,
-                    flat_lowering: Optional[str] = None,
                     quant: str = "off", ef: Optional[jax.Array] = None):
     """Neighbor-only gossip mixing on the MixPlan flat layout.
 
@@ -539,7 +401,6 @@ def mix_tree_sparse(W: jax.Array, lora, mask_a, mask_b, *, comm_plan,
     plan = plan if plan is not None else get_mix_plan(lora)
     leaves = jax.tree_util.tree_leaves(lora)
     m = plan.m
-    use_flat = sparse_use_flat(flat_lowering)
     if quant not in MIX_QUANT_MODES:
         raise ValueError(f"unknown mix quant mode {quant!r}; "
                          f"known: {MIX_QUANT_MODES}")
@@ -575,8 +436,7 @@ def mix_tree_sparse(W: jax.Array, lora, mask_a, mask_b, *, comm_plan,
         distributed = False
     if distributed:
         res = _exchange_and_mix(W, flat, prev_flat, mask_a, mask_b,
-                                plan, comm_plan, mesh, use_flat,
-                                quant=quant, ef=ef)
+                                plan, comm_plan, mesh, quant=quant, ef=ef)
         mixed, ef_new = res if quant != "off" else (res, None)
     else:
         w_rows = W.astype(flat.dtype)
@@ -587,29 +447,23 @@ def mix_tree_sparse(W: jax.Array, lora, mask_a, mask_b, *, comm_plan,
             ef_new = s - dequantize_rows(q, scale)
             w_off, w_diag = _split_diag(w_rows, 0)
             mixed = _sparse_contract_quant(w_off, flat, q, scale, mask_a,
-                                           mask_b, plan, use_flat, w_diag)
+                                           mask_b, plan, w_diag)
         elif prev_flat is not None:
             w_rows, w_diag = _split_diag(w_rows, 0)
             mixed = _sparse_contract(w_rows, flat, prev_flat, mask_a,
-                                     mask_b, plan, use_flat, w_diag)
+                                     mask_b, plan, w_diag)
         else:
             mixed = _sparse_contract(w_rows, flat, flat, mask_a, mask_b,
-                                     plan, use_flat)
+                                     plan)
 
-    out = []
-    for slot, leaf in zip(plan.slots, leaves):
-        chunk = mixed[:, slot.offset:slot.offset + slot.cols]
-        restored = chunk.reshape(m, *slot.lead, *slot.tail)
-        restored = jnp.moveaxis(restored, 0, len(slot.lead))
-        out.append(restored.astype(leaf.dtype))
-    tree = jax.tree_util.tree_unflatten(plan.treedef, out)
+    tree = _unflatten(plan, mixed, leaves)
     if quant != "off":
         return tree, ef_new
     return tree
 
 
 def _exchange_and_mix(W, flat, prev_flat, mask_a, mask_b, plan: MixPlan,
-                      cp, mesh, use_flat: bool, *, quant: str = "off",
+                      cp, mesh, *, quant: str = "off",
                       ef=None):
     """The distributed body: halo exchange + contraction in ONE shard_map
     region, so the per-process divergent intermediates (export rows, the
@@ -654,7 +508,7 @@ def _exchange_and_mix(W, flat, prev_flat, mask_a, mask_b, plan: MixPlan,
             zs = jax.lax.dynamic_update_slice(zs, sc_blk, (pid * m_loc, 0))
             w_off, w_diag = _split_diag(w_rows, pid * m_loc)
             mixed = _sparse_contract_quant(w_off, x_blk, zq, zs, ma, mb,
-                                           plan, use_flat, w_diag)
+                                           plan, w_diag)
             return mixed, ef_new
         z = jnp.zeros((m, cols), x_blk.dtype)
         if k > 0:
@@ -665,8 +519,7 @@ def _exchange_and_mix(W, flat, prev_flat, mask_a, mask_b, plan: MixPlan,
         w_diag = None
         if overlap:
             w_rows, w_diag = _split_diag(w_rows, pid * m_loc)
-        return _sparse_contract(w_rows, x_blk, z, ma, mb, plan, use_flat,
-                                w_diag)
+        return _sparse_contract(w_rows, x_blk, z, ma, mb, plan, w_diag)
 
     in_specs = [P(), P(axis, None), P(), P()]
     args = [W.astype(flat.dtype), flat, mask_a, mask_b]

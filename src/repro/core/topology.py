@@ -296,10 +296,11 @@ def rho_sq_from_samples(Ws) -> float:
 def lemma_a10_gap_bound(adj: np.ndarray, p: float,
                         c_mix: float = 0.5) -> float:
     """Lemma A.10's spectral-gap lower bound 1−ρ ≥ c_mix·p·λ2(L) for
-    edge-activation gossip on `adj` (capped at 1: the gap cannot exceed
-    1). Conformance tests check measured gaps against this with a
-    conservative empirical c_mix."""
-    return float(min(c_mix * p * lambda2(adj), 1.0))
+    edge-activation gossip on `adj`, in [0, 1]: the gap cannot exceed 1,
+    and λ2 of a Laplacian is ≥ 0, so its rounding below 0 on a
+    disconnected graph is clamped. Conformance tests check measured gaps
+    against this with a conservative empirical c_mix."""
+    return float(min(c_mix * p * max(0.0, lambda2(adj)), 1.0))
 
 
 # ---------------------------------------------------------------------------

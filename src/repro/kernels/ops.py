@@ -119,6 +119,20 @@ def expert_matmul(x, w, group_sizes, layer=None):
                       interpret=(m == "interpret"))
 
 
+_GOSSIP_BP = 512    # gossip_mix column stripe width
+
+
+def _on_whole_stripes(call, *xs):
+    """``call(*xs)`` on (rows, P) buffers zero-padded to whole gossip_mix
+    stripes, with the (rows, P) result sliced back. Zero columns mix to
+    zero columns, so the padding is lossless."""
+    P = xs[0].shape[1]
+    pad = (-P) % _GOSSIP_BP
+    if not pad:
+        return call(*xs)
+    return call(*(jnp.pad(x, ((0, 0), (0, pad))) for x in xs))[:, :P]
+
+
 def gossip_mix_flat(w: jax.Array, x: jax.Array, mask: jax.Array | float = 1.0):
     """Mix a flattened (m, P) client buffer: y = (mask·W + (1−mask)·I) @ x."""
     m_ = x.shape[0]
@@ -127,13 +141,8 @@ def gossip_mix_flat(w: jax.Array, x: jax.Array, mask: jax.Array | float = 1.0):
     mode = _mode()
     if mode == "ref":
         return ref.gossip_mix_ref(w_eff, x)
-    P = x.shape[1]
-    bp = 512
-    pad = (-P) % bp
-    if pad:
-        x_p = jnp.pad(x, ((0, 0), (0, pad)))
-        return _gossip(w_eff, x_p, interpret=(mode == "interpret"))[:, :P]
-    return _gossip(w_eff, x, interpret=(mode == "interpret"))
+    return _on_whole_stripes(
+        lambda x_: _gossip(w_eff, x_, interpret=(mode == "interpret")), x)
 
 
 def gossip_mix_seg(w: jax.Array, x: jax.Array, seg: jax.Array):
@@ -144,15 +153,9 @@ def gossip_mix_seg(w: jax.Array, x: jax.Array, seg: jax.Array):
     mode = _mode()
     if mode == "ref":
         return ref.gossip_mix_seg_ref(w, x, seg)
-    P = x.shape[1]
-    bp = 512
-    pad = (-P) % bp
-    if pad:
-        x_p = jnp.pad(x, ((0, 0), (0, pad)))
-        s_p = jnp.pad(seg, ((0, 0), (0, pad)))
-        return _gossip(w, x_p, s_p,
-                       interpret=(mode == "interpret"))[:, :P]
-    return _gossip(w, x, seg, interpret=(mode == "interpret"))
+    return _on_whole_stripes(
+        lambda x_, s_: _gossip(w, x_, s_, interpret=(mode == "interpret")),
+        x, seg)
 
 
 def gossip_mix_quant(w_off: jax.Array, q: jax.Array, scale: jax.Array,
@@ -166,17 +169,10 @@ def gossip_mix_quant(w_off: jax.Array, q: jax.Array, scale: jax.Array,
     mode = _mode()
     if mode == "ref":
         return ref.gossip_mix_quant_ref(w_off, q, scale, x, w_diag, seg)
-    P = x.shape[1]
-    bp = 512
-    pad = (-P) % bp
-    if pad:
-        q_p = jnp.pad(q, ((0, 0), (0, pad)))
-        x_p = jnp.pad(x, ((0, 0), (0, pad)))
-        s_p = jnp.pad(seg, ((0, 0), (0, pad)))
-        return _gossip_quant(w_off, q_p, scale, x_p, w_diag, s_p,
-                             interpret=(mode == "interpret"))[:, :P]
-    return _gossip_quant(w_off, q, scale, x, w_diag, seg,
-                         interpret=(mode == "interpret"))
+    return _on_whole_stripes(
+        lambda q_, x_, s_: _gossip_quant(w_off, q_, scale, x_, w_diag, s_,
+                                         interpret=(mode == "interpret")),
+        q, x, seg)
 
 
 def rglru_scan(a, u):

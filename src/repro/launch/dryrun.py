@@ -34,13 +34,9 @@ from repro.roofline.analysis import (HW, collective_bytes_from_hlo,
 
 def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
               local_steps: int = 1, axis_map=None,
-              mix_impl: str = "planned", mix_flat_lowering: str = "flat",
               moe_dispatch: str = "dense",
               seq_parallel: bool = False,
               client_parallel: bool = False) -> dict:
-    # mix_flat_lowering defaults to "flat" here (not "auto"): the dry-run
-    # simulates production TPU meshes on CPU host devices, so "auto" would
-    # analyze the off-TPU per-segment path instead of the pod's real one
     from repro.models import moe as moe_mod
     moe_mod.set_dispatch(moe_dispatch)
     cfg = get_config(arch)
@@ -68,8 +64,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
                  "chips": n_chips, "local_steps": local_steps}
     try:
         step, specs, n_tokens, training = steps_mod.build(
-            cfg, shape, mesh, local_steps=local_steps, axis_map=amap,
-            mix_impl=mix_impl, mix_flat_lowering=mix_flat_lowering)
+            cfg, shape, mesh, local_steps=local_steps, axis_map=amap)
 
         t0 = time.time()
         lowered = jax.jit(step).lower(*specs)
@@ -120,13 +115,9 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
     return rec
 
 
-def _combo_key(arch, shape, mesh_name, local_steps, mix_impl, tag="",
-               mix_flat_lowering="flat"):
-    # mix_impl is part of the key (cached per_leaf results must not be
-    # served as planned ones); other variant flags go through --tag
-    k = f"{arch}|{shape}|{mesh_name}|ls{local_steps}|mix:{mix_impl}"
-    if mix_flat_lowering != "flat":
-        k += f"|mfl:{mix_flat_lowering}"
+def _combo_key(arch, shape, mesh_name, local_steps, tag=""):
+    # variant flags go through --tag
+    k = f"{arch}|{shape}|{mesh_name}|ls{local_steps}"
     return k + (f"|{tag}" if tag else "")
 
 
@@ -140,12 +131,6 @@ def main() -> None:
     ap.add_argument("--local-steps", type=int, default=1)
     ap.add_argument("--out", default="results/dryrun.json")
     ap.add_argument("--tag", default="", help="cache-key suffix for variants")
-    ap.add_argument("--mix-impl", default="planned",
-                    choices=("planned", "per_leaf", "concat"))
-    ap.add_argument("--mix-flat-lowering", default="flat",
-                    choices=("auto", "flat", "per_segment"),
-                    help="planned-path buffer lowering to analyze "
-                         "(default: the pod's flat path)")
     ap.add_argument("--moe-dispatch", default="dense",
                     choices=("dense", "fused"))
     ap.add_argument("--seq-parallel", action="store_true")
@@ -171,9 +156,7 @@ def main() -> None:
 
     for arch, shape, mp in combos:
         mesh_name = "multi" if mp else "single"
-        key = _combo_key(arch, shape, mesh_name, args.local_steps,
-                         args.mix_impl, args.tag,
-                         mix_flat_lowering=args.mix_flat_lowering)
+        key = _combo_key(arch, shape, mesh_name, args.local_steps, args.tag)
         if key in results and results[key].get("status") in ("ok", "skipped") \
                 and not args.force:
             print(f"[cached] {key}: {results[key]['status']}")
@@ -181,8 +164,6 @@ def main() -> None:
         print(f"[run] {key} ...", flush=True)
         t0 = time.time()
         rec = run_combo(arch, shape, mp, local_steps=args.local_steps,
-                        mix_impl=args.mix_impl,
-                        mix_flat_lowering=args.mix_flat_lowering,
                         moe_dispatch=args.moe_dispatch,
                         seq_parallel=args.seq_parallel,
                         client_parallel=args.client_parallel)

@@ -111,8 +111,7 @@ def fl_geometry(mesh: Mesh, shape: InputShape,
 # ---------------------------------------------------------------------------
 
 def make_train_step(cfg: ModelConfig, *, local_steps: int = 1,
-                    lr: float = 2e-4, mix_impl: str = "planned",
-                    mix_flat_lowering: Optional[str] = None):
+                    lr: float = 2e-4):
     opt = AdamW(lr=lr)
 
     def loss_fn(base_params, lo, micro):
@@ -120,9 +119,7 @@ def make_train_step(cfg: ModelConfig, *, local_steps: int = 1,
                           micro["targets"], frontend=micro.get("frontend"),
                           lora=lo)[0]
 
-    round_fn = build_round(loss_fn, opt, local_steps=local_steps,
-                           mix_impl=mix_impl,
-                           mix_flat_lowering=mix_flat_lowering)
+    round_fn = build_round(loss_fn, opt, local_steps=local_steps)
     return round_fn, opt
 
 
@@ -232,13 +229,10 @@ def decode_input_specs(cfg: ModelConfig, shape: InputShape, mesh: Mesh, *,
 
 def build(cfg: ModelConfig, shape: InputShape, mesh: Mesh, *,
           local_steps: int = 1, dtype=jnp.bfloat16,
-          axis_map: Optional[dict] = None, mix_impl: str = "planned",
-          mix_flat_lowering: Optional[str] = None):
+          axis_map: Optional[dict] = None):
     """Returns (step_fn, input_specs, n_tokens, training_flag)."""
     if shape.kind == "train":
-        step, _ = make_train_step(cfg, local_steps=local_steps,
-                                  mix_impl=mix_impl,
-                                  mix_flat_lowering=mix_flat_lowering)
+        step, _ = make_train_step(cfg, local_steps=local_steps)
         specs = train_input_specs(cfg, shape, mesh,
                                   local_steps=local_steps, dtype=dtype,
                                   axis_map=axis_map)

@@ -39,8 +39,6 @@ def main() -> None:
     ap.add_argument("--adaptive-t", action="store_true",
                     help="online T via the control plane's spectral "
                          "estimator (ControlConfig t_policy='adaptive')")
-    ap.add_argument("--mix-flat-lowering", default="auto",
-                    choices=("auto", "flat", "per_segment"))
     ap.add_argument("--full", action="store_true",
                     help="full (non-reduced) architecture config")
     ap.add_argument("--seed", type=int, default=0)
@@ -56,7 +54,7 @@ def main() -> None:
         control={"t_policy": "adaptive"} if args.adaptive_t else None,
         rounds=args.rounds, local_steps=args.local_steps,
         batch_size=args.batch, seq_len=args.seq, lr=args.lr,
-        mix_flat_lowering=args.mix_flat_lowering, seed=args.seed,
+        seed=args.seed,
         # the Session loop rebinds lora/opt_state every round, so the
         # round updates them in place (no per-round copy of client state)
         donate=True,

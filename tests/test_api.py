@@ -1,7 +1,7 @@
 """`repro.api` contract tests: DFLConfig validation/keys, Session parity
 against the legacy hand-wired round loop (bit-for-bit at fixed seed),
 static-vs-adaptive MaskSchedule parity at T=1, checkpoint/resume replay,
-callbacks, and the mix_flat_lowering knob."""
+and callbacks."""
 import os
 
 import jax
@@ -12,7 +12,7 @@ import pytest
 from repro.api import (AdaptiveSchedule, ConsoleLogger, DFLConfig,
                        HistoryRecorder, Session, StaticSchedule)
 from repro.core import (build_lora_tree, make_dfl_round, make_topology,
-                        mixing, round_masks)
+                        round_masks)
 from repro.data.synthetic import lm_token_stream
 from repro.optim import AdamW
 
@@ -40,10 +40,6 @@ def test_config_validation():
         DFLConfig(task="sst2", model="gemma3-1b")    # classifier != encoder
     with pytest.raises(ValueError):
         DFLConfig(task="lm", model="encoder")        # lm needs an arch
-    with pytest.raises(ValueError):
-        DFLConfig(mix_impl="magic")
-    with pytest.raises(ValueError):
-        DFLConfig(mix_flat_lowering="sometimes")
     with pytest.raises(ValueError):
         DFLConfig(rounds=0)
     with pytest.raises(ValueError):
@@ -270,44 +266,6 @@ def test_evaluate_classifier_only():
                            seq_len=16, T=1))
     with pytest.raises(ValueError):
         lm.evaluate()
-
-
-# ---------------------------------------------------------------------------
-# mix_flat_lowering knob
-# ---------------------------------------------------------------------------
-
-def test_flat_lowering_knob_resolution():
-    assert mixing.use_flat_lowering("flat") is True
-    assert mixing.use_flat_lowering("per_segment") is False
-    on_tpu = jax.default_backend() == "tpu"
-    assert mixing.use_flat_lowering("auto") is on_tpu
-    with pytest.raises(ValueError):
-        mixing.use_flat_lowering("sometimes")
-    prev = mixing.set_flat_lowering("per_segment")
-    try:
-        assert mixing.flat_lowering_mode() == "per_segment"
-        assert mixing.use_flat_lowering() is False
-    finally:
-        mixing.set_flat_lowering(prev)
-    with pytest.raises(ValueError):
-        mixing.set_flat_lowering("sometimes")
-
-
-def test_flat_and_per_segment_lowerings_agree(key):
-    """Forcing the flat (m, P) buffer off-TPU must stay numerically equal
-    to the per-segment dots (the gated path is a lowering, not a math
-    change)."""
-    m = 4
-    tree = {"l": {"a": jax.random.normal(key, (m, 12, 4)),
-                  "b": jax.random.normal(jax.random.fold_in(key, 1),
-                                         (m, 4, 12))}}
-    W = jnp.full((m, m), 1.0 / m, jnp.float32)
-    flat = mixing.mix_tree_planned(W, tree, 1.0, 0.3, flat_lowering="flat")
-    seg = mixing.mix_tree_planned(W, tree, 1.0, 0.3,
-                                  flat_lowering="per_segment")
-    for a, b in zip(jax.tree.leaves(flat), jax.tree.leaves(seg)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -246,8 +246,7 @@ def _four_chip_round_hlo(topo, cfg) -> str:
         _spec((M_CLIENTS, M_CLIENTS), jnp.float32, rep),
         _spec((4,), jnp.float32, rep),
     )
-    round_fn = build_round(loss_fn, opt, local_steps=ls,
-                           mix_flat_lowering="flat")
+    round_fn = build_round(loss_fn, opt, local_steps=ls)
     sharding.set_mesh(mesh)
     try:
         return _compile(round_fn, *specs)
@@ -309,8 +308,7 @@ def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
                             tree)
 
     tok = _spec((ls, M_CLIENTS, b, S), jnp.int32, one_chip)
-    round_fn = build_round(loss_fn, opt, local_steps=ls,
-                           mix_flat_lowering="flat", donate=True)
+    round_fn = build_round(loss_fn, opt, local_steps=ls, donate=True)
     compiled = round_fn.lower(
         on_chip(base), on_chip(lora), on_chip(jax.eval_shape(opt.init, lora)),
         {"tokens": tok, "targets": tok},

@@ -155,33 +155,22 @@ def test_schedule_support_edge_activation_is_edges():
 # mix_tree_sparse numerics (single-process degenerate path)
 # ---------------------------------------------------------------------------
 
-def test_sparse_mix_bitwise_equals_dense():
+@pytest.mark.parametrize("ma,mb", [(1.0, 1.0), (1.0, 0.0), (0.0, 1.0),
+                                   (0.3, 0.8)])
+def test_sparse_mix_bitwise_equals_dense(ma, mb):
     """The sparse contraction is the SAME arithmetic as the planned dense
     path at the same operand layout: bitwise at the BINARY masks every
-    paper method actually passes (RoundMasks are 0/1 scalars), float-equal
-    at fractional (damped-variant) masks where the blend forms differ,
-    with and without a (1-shard) CommPlan attached."""
+    paper method actually passes (RoundMasks are 0/1 scalars) and at
+    fractional (damped-variant) masks, with and without a (1-shard)
+    CommPlan attached."""
     W = jnp.asarray(metropolis_weights(ring_graph(8)), jnp.float32)
     lora = _tree(jax.random.PRNGKey(0))
     cp = comm.build_comm_plan(ring_graph(8), n_shards=1)
-    for ma, mb in ((1.0, 1.0), (1.0, 0.0), (0.0, 1.0), (0.3, 0.8)):
-        binary = {ma, mb} <= {0.0, 1.0}
-        dense = mixing.mix_tree_planned(W, lora, ma, mb,
-                                        flat_lowering="flat")
-        for plan in (None, cp):
-            for lowering in ("flat", "per_segment"):
-                sparse = mixing.mix_tree_sparse(W, lora, ma, mb,
-                                                comm_plan=plan,
-                                                flat_lowering=lowering)
-                for x, y in zip(jax.tree.leaves(dense),
-                                jax.tree.leaves(sparse)):
-                    if binary or lowering == "flat":
-                        np.testing.assert_array_equal(np.asarray(x),
-                                                      np.asarray(y))
-                    else:
-                        np.testing.assert_allclose(np.asarray(x),
-                                                   np.asarray(y),
-                                                   rtol=1e-5, atol=1e-6)
+    dense = mixing.mix_tree_planned(W, lora, ma, mb)
+    for plan in (None, cp):
+        sparse = mixing.mix_tree_sparse(W, lora, ma, mb, comm_plan=plan)
+        for x, y in zip(jax.tree.leaves(dense), jax.tree.leaves(sparse)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_sparse_overlap_delayed_semantics():
@@ -249,39 +238,6 @@ def test_sparse_mesh_plan_mismatch_raises(monkeypatch):
     # conformance tier's rate probes depend on it)
     out = mixing.mix_tree_sparse(W, lora, 1.0, 1.0, comm_plan=None)
     assert jax.tree.structure(out) == jax.tree.structure(lora)
-
-
-def test_sparse_lowering_auto_pins_flat():
-    """`sparse_use_flat` auto pins the flat fused dot exactly where the
-    fused gossip kernel lives (TPU meshes) and per-slot dots elsewhere —
-    the dense path's heuristic, VALIDATED for the sparse path by the
-    BENCH_multihost.json `sparse_lowering` probe (the sunk-flat-buffer
-    argument for always-flat measured slower on CPU: the per-column seg
-    blend costs more than per-slot scalar blends). Explicit pins always
-    win, and BOTH lowerings stay bitwise equal."""
-    on_tpu = jax.default_backend() == "tpu"
-    assert mixing.sparse_use_flat("auto") is on_tpu
-    assert mixing.sparse_use_flat(None) is on_tpu   # default defers to auto
-    assert mixing.sparse_use_flat("flat") is True
-    assert mixing.sparse_use_flat("per_segment") is False
-    with pytest.raises(ValueError):
-        mixing.sparse_use_flat("fused")
-    prev = mixing.set_flat_lowering("per_segment")
-    try:
-        # an explicit process default IS honoured by the sparse resolver
-        assert mixing.sparse_use_flat(None) is False
-    finally:
-        mixing.set_flat_lowering(prev)
-
-    W = jnp.asarray(metropolis_weights(ring_graph(8)), jnp.float32)
-    lora = _tree(jax.random.PRNGKey(3))
-    for ma, mb in ((1.0, 1.0), (1.0, 0.0), (0.0, 1.0)):
-        flat = mixing.mix_tree_sparse(W, lora, ma, mb, comm_plan=None,
-                                      flat_lowering="flat")
-        seg = mixing.mix_tree_sparse(W, lora, ma, mb, comm_plan=None,
-                                     flat_lowering="per_segment")
-        for x, y in zip(jax.tree.leaves(flat), jax.tree.leaves(seg)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------

@@ -146,28 +146,51 @@ def test_identity_mixing_is_noop(setup):
 
 
 def test_planned_round_matches_per_leaf_oracle(setup):
-    """The default round (planned fused mixing) must match a per_leaf
-    round bit-for-bit at equal mix masks — same batch, same W, same
-    state in, identical state out."""
-    cfg, base, lora0, opt, _, task, parts = setup
-
-    def loss_fn(bp, lo, micro):
-        return classifier_loss(bp, cfg, micro["tokens"], micro["labels"],
-                               lora=lo)
-
-    rf_planned = jax.jit(make_dfl_round(loss_fn, opt, local_steps=2))
-    rf_oracle = jax.jit(make_dfl_round(loss_fn, opt, local_steps=2,
-                                       mix_impl="per_leaf"))
+    """The round's planned fused mix must match the per-leaf oracle: the
+    same round at W = I (the local steps alone) followed by
+    `mix_tree(W, ...)` — same batch, same W, same state in."""
+    _, base, lora0, opt, round_fn, task, parts = setup
     batch = jax.tree.map(jnp.asarray,
                          next(iter(federated_batches(task, parts, 8, 2, 1))))
     topo = make_topology("complete", M, p=0.5, seed=7)
     W = jnp.asarray(topo.sample(), jnp.float32)
     masks = round_masks("lora", 0, 1).as_array()    # equal mix masks
-    st1 = opt.init(lora0)
-    st2 = opt.init(lora0)
-    l1, o1, m1 = rf_planned(base, lora0, st1, batch, W, masks)
-    l2, o2, m2 = rf_oracle(base, lora0, st2, batch, W, masks)
-    for a, b in zip(jax.tree.leaves((l1, o1)), jax.tree.leaves((l2, o2))):
+    l1, o1, m1 = round_fn(base, lora0, opt.init(lora0), batch, W, masks)
+    l0, o2, m2 = round_fn(base, lora0, opt.init(lora0), batch,
+                          jnp.eye(M, dtype=jnp.float32), masks)
+    l2 = mix_tree(W, l0, masks[2], masks[3])
+    for a, b in zip(jax.tree.leaves(l1), jax.tree.leaves(l2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(o1), jax.tree.leaves(o2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(m1["loss"]),
                                   np.asarray(m2["loss"]))
+
+
+def test_round_mixes_with_one_gossip_kernel_call(setup, monkeypatch):
+    """One traced round calls `ops.gossip_mix_seg` exactly once, with the
+    masks as traced inputs, so every method and phase runs the one flat
+    lowering the chip runs."""
+    from repro.kernels import ops
+    cfg, base, lora0, opt, _, task, parts = setup
+    calls = []
+    real = ops.gossip_mix_seg
+
+    def counting(*args):
+        calls.append(args[1].shape)
+        return real(*args)
+
+    monkeypatch.setattr(ops, "gossip_mix_seg", counting)
+
+    def loss_fn(bp, lo, micro):
+        return classifier_loss(bp, cfg, micro["tokens"], micro["labels"],
+                               lora=lo)
+
+    batch = jax.tree.map(jnp.asarray,
+                         next(iter(federated_batches(task, parts, 8, 2, 1))))
+    jax.make_jaxpr(make_dfl_round(loss_fn, opt, local_steps=2))(
+        base, lora0, opt.init(lora0), batch,
+        jnp.eye(M, dtype=jnp.float32), jnp.zeros((4,), jnp.float32))
+    assert len(calls) == 1
+    assert calls[0][0] == M and calls[0][1] % 512 == 0

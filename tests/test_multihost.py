@@ -18,12 +18,19 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.api import ClusterSession, DFLConfig, HistoryRecorder, Session
+from repro.api.rounds import build_round
 from repro.checkpoint import load_pytree
+from repro.core import build_lora_tree, round_masks
 from repro.core.topology import make_topology
+from repro.data import federated_batches, label_skew_partitions, make_task
 from repro.dist import multihost, sharding
 from repro.launch.cluster import failed_ranks, spawn_simulated
+from repro.models.classifier import (classifier_loss, encoder_config,
+                                     init_classifier)
+from repro.optim import AdamW
 from repro.scenarios.schedule import BroadcastSchedule, GossipSchedule
 
 ENC_KW = dict(n_layers=1, d_model=32, n_heads=2, d_ff=64, vocab_size=256)
@@ -85,26 +92,52 @@ def test_broadcast_schedule_passthrough_single_process():
         np.testing.assert_array_equal(inner.next_w(t), wrapped.next_w(t))
 
 
-def test_mix_gather_modes_and_key():
-    with pytest.raises(ValueError):
-        _clf_config(mix_gather="sometimes")
-    on, off = _clf_config(mix_gather="on"), _clf_config(mix_gather="off")
-    assert on.cache_key() != off.cache_key()
-    from repro.api.session import _resolve_mix_gather
-    assert _resolve_mix_gather("on") is True
-    assert _resolve_mix_gather("off") is False
-    # single-process "auto" resolves off
-    assert _resolve_mix_gather("auto") is (jax.process_count() > 1)
+def test_session_mix_gather_follows_process_count(monkeypatch):
+    """`Session` builds its round with the pre-mix all-gather on exactly
+    when the run spans processes."""
+    from repro.api import session as session_mod
+    seen = []
+    real = session_mod.build_round
+
+    def recording(*args, **kw):
+        seen.append(kw)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(session_mod, "build_round", recording)
+    session_mod.clear_build_cache()
+    Session(_clf_config(rounds=1))
+    assert [kw["mix_gather"] for kw in seen] == [jax.process_count() > 1]
 
 
 def test_mix_gather_bitwise_noop_single_process():
     """mix_gather pins the communication lowering; it must not change a
     single bit of the single-process numerics."""
-    a = Session(_clf_config(rounds=3, mix_gather="off"))
-    b = Session(_clf_config(rounds=3, mix_gather="on"))
-    a.run()
-    b.run()
-    _assert_trees_equal(a.lora, b.lora)
+    cfg = encoder_config(**ENC_KW)
+    base = init_classifier(jax.random.key(0), cfg, n_classes=2)
+    lora0 = build_lora_tree(jax.random.key(1), base, cfg, n_clients=4)
+    opt = AdamW(lr=1e-3)
+
+    def loss_fn(bp, lo, micro):
+        return classifier_loss(bp, cfg, micro["tokens"], micro["labels"],
+                               lora=lo)
+
+    topo = make_topology("complete", 4, p=0.5, seed=0)
+    feed = [(jax.tree.map(jnp.asarray, batch),
+             jnp.asarray(topo.sample(), jnp.float32),
+             round_masks("tad", t, 2).as_array())
+            for t, batch in enumerate(federated_batches(
+                make_task("sst2", vocab_size=256),
+                label_skew_partitions(2, 4), 8, 2, 3, seed=0))]
+    out = []
+    for gather in (False, True):
+        round_fn = jax.jit(build_round(loss_fn, opt, local_steps=2,
+                                       mix_gather=gather))
+        lora, opt_state = lora0, opt.init(lora0)
+        for batch, W, masks in feed:
+            lora, opt_state, _ = round_fn(base, lora, opt_state, batch, W,
+                                          masks)
+        out.append(lora)
+    _assert_trees_equal(*out)
 
 
 def test_cluster_session_degenerate_matches_session():

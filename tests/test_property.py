@@ -15,7 +15,7 @@ else:
         "hypothesis", reason="property tests need hypothesis (optional dep)")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core import mix_tree, mix_tree_concat, sample_mixing_matrix
+from repro.core import mix_tree, mix_tree_planned, sample_mixing_matrix
 from repro.core.diagnostics import consensus_stats
 from repro.core.topology import (complete_graph, lambda2, make_topology,
                                  ring_graph)
@@ -53,8 +53,8 @@ def test_gossip_preserves_mean(m, p, seed):
 
 @given(m=st.integers(3, 8), seed=st.integers(0, 50))
 @settings(**SETTINGS)
-def test_mix_concat_equals_per_leaf(m, seed):
-    """The fused single-buffer mixing lowering is numerically identical to
+def test_mix_planned_equals_per_leaf(m, seed):
+    """The fused single-buffer planned mix is numerically identical to
     per-leaf mixing."""
     rng = np.random.default_rng(seed)
     W = jnp.asarray(sample_mixing_matrix(complete_graph(m), 0.5, rng),
@@ -66,7 +66,7 @@ def test_mix_concat_equals_per_leaf(m, seed):
                   "b": jnp.asarray(rng.normal(size=(3, m, 2, 4)),
                                    jnp.float32)}}
     m1 = mix_tree(W, tree, 1.0, 0.3)
-    m2 = mix_tree_concat(W, tree, 1.0, 0.3)
+    m2 = mix_tree_planned(W, tree, 1.0, 0.3)
     for l1, l2 in zip(jax.tree.leaves(m1), jax.tree.leaves(m2)):
         np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                    rtol=1e-5, atol=1e-5)

@@ -136,25 +136,23 @@ def test_quant_mix_close_to_exact_and_updates_ef(key):
     plan = mixing.get_mix_plan(lora)
     ef0 = jnp.zeros((8, plan.cols), jnp.float32)
     exact = mixing.mix_tree_sparse(W, lora, 1.0, 1.0, comm_plan=None)
-    for lowering in ("flat", "per_segment"):
-        mixed, ef1 = mixing.mix_tree_sparse(
-            W, lora, 1.0, 1.0, comm_plan=None, flat_lowering=lowering,
-            quant="int8", ef=ef0)
-        # int8 off-diagonal noise stays ~1% of the signal
-        for a, b in zip(jax.tree.leaves(exact), jax.tree.leaves(mixed)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=0.05)
-        assert ef1.shape == (8, plan.cols)
-        assert float(jnp.abs(ef1).max()) > 0          # residual captured
-        # EF is exactly the quantization residual of the source rows
-        flat = jnp.concatenate(
-            [jnp.moveaxis(x, -3, 0).reshape(8, -1)
-             for x in jax.tree.leaves(lora)], axis=1)
-        q, scale = mixing.quantize_rows(flat, "int8")
-        np.testing.assert_allclose(
-            np.asarray(ef1),
-            np.asarray(flat - mixing.dequantize_rows(q, scale)),
-            rtol=1e-5, atol=1e-7)
+    mixed, ef1 = mixing.mix_tree_sparse(
+        W, lora, 1.0, 1.0, comm_plan=None, quant="int8", ef=ef0)
+    # int8 off-diagonal noise stays ~1% of the signal
+    for a, b in zip(jax.tree.leaves(exact), jax.tree.leaves(mixed)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=0.05)
+    assert ef1.shape == (8, plan.cols)
+    assert float(jnp.abs(ef1).max()) > 0          # residual captured
+    # EF is exactly the quantization residual of the source rows
+    flat = jnp.concatenate(
+        [jnp.moveaxis(x, -3, 0).reshape(8, -1)
+         for x in jax.tree.leaves(lora)], axis=1)
+    q, scale = mixing.quantize_rows(flat, "int8")
+    np.testing.assert_allclose(
+        np.asarray(ef1),
+        np.asarray(flat - mixing.dequantize_rows(q, scale)),
+        rtol=1e-5, atol=1e-7)
 
 
 def test_quant_overlap_reads_prev_round_sources(key):
