@@ -283,7 +283,8 @@ def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
     float32 (..., 64, 1408) tensor is left, and arguments plus
     temporaries fit the chip's 15.75 GB. The layer remat keeps the routed
     rows, so the scanned layer holds three `moe_gmm` calls (gate, up,
-    down) and three `moe_gmm_t` (their backwards): no recomputed forward."""
+    down) and three `moe_gmm_t` (their backwards): no recomputed forward.
+    The rows' backward is gathers, with no float32 scatter over them."""
     import dataclasses
     import re
 
@@ -320,6 +321,9 @@ def test_moe_cell_round_compiles_routed_on_one_chip(topo, one_chip, pallas):
         n = sum(bool(re.search(rf"%{name}[.\s]", ln)) for ln in calls)
         assert n == 3, (name, n)
     assert not re.search(r"f32\[[\d,]*64,1408\]", hlo)
+    # the routed rows' backward gathers by the inverse permutation: no
+    # scatter-add over the 2048 tokens x top-6 rows
+    assert not re.search(r"f32\[12288,2048\]\{[^}]*\} scatter\(", hlo)
     # the kernels read each layer's blocks from the stack: no layer's
     # expert projection is copied out of it
     assert not re.search(r"(f32|bf16)\[(1,)?64,(2048,1408|1408,2048)\]",

@@ -159,6 +159,33 @@ def test_routed_moe_equals_dense_dispatch(shared, backend):
     assert int(load_r.sum()) == 2 * 3 * 16 * cfg.top_k
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("k", [1, 2, 6])
+def test_gather_rows_equals_the_indexed_gather(k, seed):
+    """`moe._gather_rows` against plain indexing under JAX's autodiff, in
+    value and VJP, bit for bit: the token gather into expert order (each
+    token read k times, its k cotangents summed) and the gather back."""
+    T, d, E = 24, 16, 5
+    ks = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(ks[0], (T, d))
+    rows = jax.random.normal(ks[1], (T * k, d))
+    dy = jax.random.normal(ks[2], (T * k, d))
+    order = jnp.argsort(jax.random.randint(ks[3], (T * k,), 0, E))
+    back = jnp.argsort(order)
+    cases = [
+        (x, lambda a: moe_mod._gather_rows(a, order // k, back),
+         lambda a: jnp.repeat(a, k, axis=0)[order]),
+        (rows, lambda a: moe_mod._gather_rows(a, back, order),
+         lambda a: a[back]),
+    ]
+    for a, got, want in cases:
+        y_got, vjp_got = jax.vjp(got, a)
+        y_want, vjp_want = jax.vjp(want, a)
+        np.testing.assert_array_equal(np.asarray(y_got), np.asarray(y_want))
+        np.testing.assert_array_equal(np.asarray(vjp_got(dy)[0]),
+                                      np.asarray(vjp_want(dy)[0]))
+
+
 def test_routed_is_the_default_without_an_expert_axis():
     assert moe_mod._resolve(None) == "routed"
     assert moe_mod._resolve("fused") == "fused"
@@ -234,13 +261,35 @@ def test_routed_moe_lora_gradients_equal_dense(shared, backend, monkeypatch):
                                    rtol=1e-4, atol=1e-5 * scale)
 
 
-def _count(jaxpr, primitive: str) -> int:
-    """Equations of ``primitive`` in a jaxpr and every jaxpr nested in it
-    (scan, remat and custom-vjp bodies), each body counted once."""
-    return sum((eqn.primitive.name == primitive)
-               + sum(_count(sub, primitive)
+def _count(jaxpr, primitive: str, where=lambda eqn: True) -> int:
+    """Equations of ``primitive`` (those ``where`` holds for) in a jaxpr and
+    every jaxpr nested in it (scan, remat and custom-vjp bodies), each body
+    counted once."""
+    return sum((eqn.primitive.name == primitive and where(eqn))
+               + sum(_count(sub, primitive, where)
                      for sub in jax.core.jaxprs_in_params(eqn.params))
                for eqn in jaxpr.eqns)
+
+
+def test_routed_backward_gathers_the_rows_without_scatter_adds():
+    """The routed rows' backward gathers by the inverse permutation: the
+    gradient of the routed layer scatter-adds into no (tokens x k)-row
+    buffer, where JAX's transposes of the two row gathers would."""
+    cfg = _moe_cfg(2)
+    params = moe_mod.init_moe(jax.random.key(3), cfg)
+    x = jax.random.normal(jax.random.key(4), (2, 3, 16, cfg.d_model))
+    rows = x.size // cfg.d_model * cfg.top_k
+
+    def loss(x):
+        return jnp.sum(moe_mod.moe_layer(params, cfg, x, "routed")[0] ** 2)
+
+    grad = jax.make_jaxpr(jax.grad(loss))(x)
+    assert _count(grad.jaxpr, "scatter-add",
+                  lambda eqn: eqn.invars[0].aval.shape[:1] == (rows,)) == 0
+    # the two row gathers forward, and their two backwards
+    assert _count(grad.jaxpr, "gather",
+                  lambda eqn: eqn.outvars[0].aval.shape[0] in
+                  (rows, rows // cfg.top_k)) == 4
 
 
 def test_layer_remat_keeps_the_routed_rows():
